@@ -115,9 +115,6 @@ _knob("IGNEOUS_CCL_BACKEND", "str", "",
       "(auto when unset)", "device kernels")
 _knob("IGNEOUS_CCL_DEVICE_ALGO", "str", "scan",
       "device CCL algorithm: `scan|relax`", "device kernels")
-_knob("IGNEOUS_CCL_ENGINE", "str", "",
-      "tiled-CCL engine override: `lax|pallas` (auto when unset)",
-      "device kernels")
 _knob("IGNEOUS_CCL_TILE", "str", "",
       "CCL VMEM tile `tz,ty,tx` (auto when unset)", "device kernels")
 _knob("IGNEOUS_EDT_BACKEND", "str", "",

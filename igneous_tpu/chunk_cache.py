@@ -1,6 +1,6 @@
 """Shared chunk decode cache: compressed-domain residency for hot reads.
 
-The e2e profile (BENCH_r05) pins the codec wall: single-thread inflate at
+The e2e profile pins the codec wall: single-thread inflate at
 ~350-600 MB/s plus the chunk decode bounds every read path, while the same
 chunks are fetched repeatedly — the pipeline prefetch pool, the lease
 batcher's cutout prefetch, and overlapping task cutouts all re-download

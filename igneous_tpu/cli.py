@@ -139,6 +139,9 @@ def enqueue(queue_spec: str, tasks, parallel: int = 1):
 @click.pass_context
 def main(ctx, parallel):
   """igneous-tpu: TPU-native Neuroglancer Precomputed pipelines."""
+  from .compile_cache import place_jax_cache
+
+  place_jax_cache()
   ctx.ensure_object(dict)
   # reference semantics: -p 0 means "use the number of cores"
   # (/root/reference/igneous_cli/cli.py:186)
@@ -1508,6 +1511,12 @@ def execute(ctx, queue_spec, aws_region, lease_sec, tally, num_tasks,
         p.join()
     finally:
       restore()
+    failed = [
+      p.exitcode for p in procs
+      if p.exitcode not in (0, lifecycle.EXIT_PREEMPTED)
+    ]
+    if failed:
+      raise click.ClickException(f"worker exit codes {failed}")
     if flag.is_set() or any(
       p.exitcode == lifecycle.EXIT_PREEMPTED for p in procs
     ):
@@ -1529,8 +1538,10 @@ def _execute_worker(queue_spec, lease_sec, num_tasks, exit_on_empty, min_sec,
   from . import lifecycle, telemetry
   from .observability import journal as journal_mod
   from .observability import prom
+  from .observability.device import require_worker_backend
   from .queues import TaskQueue
 
+  require_worker_backend()
   flag = lifecycle.StopFlag()
   restore = lifecycle.install_signal_handlers(flag)
   watcher = lifecycle.PreemptionWatcher(flag, sentinel=drain_sentinel)

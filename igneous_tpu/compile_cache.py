@@ -12,7 +12,7 @@ Key anatomy — an entry is only valid for the exact compile context:
 
     kernel name + input signature (shapes/dtypes/treedef repr)
     + kernel variant (the closure config a name alone can't capture:
-      pyramid factors, CCL tile/algo/engine, EDT anisotropy/line block,
+      pyramid factors, CCL tile/algo, EDT anisotropy/line block,
       infer model spec)
     + platform / device kind / device count / process count / mesh axes
     + jax AND jaxlib versions
@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import hashlib
 import json
+import os
 import pickle
 import re
 import time
@@ -91,6 +92,14 @@ def versions() -> Tuple[str, str]:
   except Exception:
     jaxlib_v = ""
   return str(jax.__version__), str(jaxlib_v)
+
+
+def _devices(mesh) -> list:
+  """The devices an executable compiled for ``mesh`` runs on: the mesh's,
+  or the default device for an un-meshed program."""
+  import jax
+
+  return list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
 
 
 def topology(mesh=None) -> dict:
@@ -163,10 +172,12 @@ def encode_entry(meta: dict, compiled, compile_s: float) -> bytes:
   return MAGIC + len(header).to_bytes(4, "big") + header + body
 
 
-def decode_entry(data: bytes, meta: dict):
+def decode_entry(data: bytes, meta: dict, devices=None):
   """(compiled, header) after full verification; raises CompileCacheError
   on any corruption, truncation, or key mismatch — never returns a
-  partially-verified executable."""
+  partially-verified executable. ``devices``: the devices the executable
+  was compiled for (default: the default device); without them JAX loads
+  it onto every local device."""
   hstart = len(MAGIC) + 4
   if len(data) < hstart or data[: len(MAGIC)] != MAGIC:
     raise CompileCacheError("bad magic")
@@ -193,7 +204,7 @@ def decode_entry(data: bytes, meta: dict):
 
     blob, in_tree, out_tree = pickle.loads(body)
     compiled = serialize_executable.deserialize_and_load(
-      blob, in_tree, out_tree
+      blob, in_tree, out_tree, execution_devices=devices or _devices(None),
     )
   except Exception as exc:
     raise CompileCacheError(f"deserialize failed: {exc}")
@@ -214,7 +225,7 @@ class CompileCache:
     self.cloudpath = cloudpath
     self.cf = CloudFiles(cloudpath)
 
-  def get(self, meta: dict):
+  def get(self, meta: dict, devices=None):
     """(compiled, header) on a fully-verified hit; None on miss. A
     corrupt or mismatched entry is quarantined and reads as a miss."""
     key = entry_key(meta)
@@ -226,7 +237,7 @@ class CompileCache:
     if data is None:
       return None
     try:
-      return decode_entry(bytes(data), meta)
+      return decode_entry(bytes(data), meta, devices)
     except CompileCacheError:
       self.quarantine(key, bytes(data))
       return None
@@ -316,7 +327,7 @@ def load_or_compile(
     try:
       meta = entry_meta(kernel, signature, mesh=mesh, variant=variant)
       t0 = time.perf_counter()
-      hit = cache.get(meta)
+      hit = cache.get(meta, _devices(mesh))
       if hit is not None:
         compiled, header = hit
         fetch_s = time.perf_counter() - t0
@@ -345,3 +356,44 @@ def load_or_compile(
   if cache is not None and meta is not None:
     cache.put(meta, compiled, time.perf_counter() - t0)
   return compiled
+
+
+def checkout_root() -> Optional[str]:
+  """The source checkout this package runs from (``pyproject.toml``
+  beside the package), or None for an installed package."""
+  root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  return root if os.path.isfile(os.path.join(root, "pyproject.toml")) else None
+
+
+def place_jax_cache() -> Optional[str]:
+  """Place JAX's own persistent compilation cache and return its
+  directory (None: no cache). Called by the CLI, ``chip_smoke.py`` and
+  ``bench.py``.
+
+  A set ``JAX_COMPILATION_CACHE_DIR`` is JAX's to read and stays as JAX
+  read it. Otherwise, in a checkout, the cache lives at
+  ``<checkout>/.jax_cache``: a fixed path, so the next process on this
+  checkout finds its entries; an installed package keeps JAX's default.
+  The minimum compile time drops to zero, unless its own variable is
+  set, so the pyramid, CCL, EDT and marching-cubes executables, which
+  compile in about a second each, are written too.
+
+  Imports no JAX, so a command that never compiles pays nothing: before
+  JAX is imported the settings go into the variables JAX reads at
+  import, after it into ``jax.config``."""
+  import sys
+
+  settings = {}
+  cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+  if cache_dir is None and checkout_root() is not None:
+    cache_dir = os.path.join(checkout_root(), ".jax_cache")
+    settings["jax_compilation_cache_dir"] = cache_dir
+  if not os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
+    settings["jax_persistent_cache_min_compile_time_secs"] = 0.0
+  jax = sys.modules.get("jax")
+  for name, value in settings.items():
+    if jax is not None:
+      jax.config.update(name, value)
+    else:
+      os.environ[name.upper()] = str(value)
+  return cache_dir

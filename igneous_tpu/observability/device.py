@@ -387,6 +387,27 @@ def publish_gauges() -> None:
 # span emission — called by the executors around each device phase
 
 
+def require_worker_backend() -> None:
+  """Fail a worker that should hold an accelerator but landed on the CPU.
+
+  With ``JAX_PLATFORMS`` unset (or naming tpu), a process that cannot get
+  the chip, such as a second worker on a one-chip host, falls back to
+  JAX's CPU backend with only a warning and would then take every host
+  path. A worker pinned with ``JAX_PLATFORMS=cpu`` is a CPU worker by
+  choice and passes."""
+  plats = os.environ.get("JAX_PLATFORMS", "")
+  if plats.split(",")[0].strip().lower() == "cpu":
+    return
+  import jax
+
+  if jax.default_backend() == "cpu":
+    raise RuntimeError(
+      "device worker has no accelerator (JAX fell back to the CPU): a "
+      "chip serves one process, so run one worker per chip with "
+      "`execute --batch K`, or pin CPU workers with JAX_PLATFORMS=cpu"
+    )
+
+
 def _devices_of(mesh=None) -> List[str]:
   if mesh is not None:
     try:
@@ -455,6 +476,24 @@ def execute_span(kernel: str, elements: int = 0, nbytes: int = 0,
                 device=devices[0] if devices else None,
                 devices=len(devices), **attrs)
     maybe_sample_profile()
+
+
+def run_solo(kernel: str, fn, args, signature, static=None,
+             elements: int = 0, nbytes: int = 0, **attrs):
+  """One un-meshed dispatch of the jitted ``fn(*args, **static)`` on the
+  default device. A fresh ``signature`` first compiles ahead of time
+  inside a compile span (which fills jit's own cache); every call then
+  runs inside an execute span, blocked on its result."""
+  import jax
+
+  static = static or {}
+  if LEDGER.note_signature(kernel, signature):
+    with compile_span(kernel, _devices_of()):
+      fn.lower(*args, **static).compile()
+  with execute_span(kernel, elements=elements, nbytes=nbytes, **attrs):
+    out = fn(*args, **static)
+    jax.block_until_ready(out)
+  return out
 
 
 @contextlib.contextmanager

@@ -183,7 +183,7 @@ def _ccl_kernel(
 # The whole-volume kernel above converges in rounds bounded by the largest
 # component's tortuosity across the FULL volume — on dense near-percolation
 # inputs that is dozens-to-hundreds of rounds, each a whole-volume sweep
-# (the ~138k vox/s BENCH_r05 measurement). The tiled kernel bounds rounds
+# (~138k vox/s measured on a CPU host). The tiled kernel bounds rounds
 # by TILE tortuosity instead: VMEM-sized blocks resolve locally (converged
 # tiles freeze — per-tile early exit), and one exact host union-find over
 # tile-face root pairs stitches the global components. Any consistent
@@ -193,30 +193,30 @@ def _ccl_kernel(
 # the native C++ two-pass — _ccl_kernel is kept as the parity oracle.
 
 _DEFAULT_TILE = (2, 4, 8)
-_DEFAULT_TILE_TPU = (8, 16, 128)
+# tiles whose output matched the CPU backend's on a v5e chip. The
+# (8,16,128) tile gave different roots there on a (128,449,449) cutout
+# (both algos; small cutouts agreed): over its 1856 tiles the reverse
+# _seg_cummin along z and x differs from the CPU's, the forward scans,
+# neighbor-min and gathers agree (tools/ccl_tile_repro.py). No other
+# tile is taken on a TPU until that repro shows no difference.
+_TPU_TILES = (_DEFAULT_TILE,)
 
 
 def _tile_shape() -> Tuple[int, int, int]:
   """(tz, ty, tx) block-local resolve tile, override with
-  IGNEOUS_CCL_TILE=tz,ty,tx.
+  IGNEOUS_CCL_TILE=tz,ty,tx (refused on a TPU unless in _TPU_TILES).
 
   Rounds scale with tile tortuosity, so smaller tiles converge in fewer
   sweeps but push more boundary edges to the host merge. Measured sweep
   on the 1-core CPU bench fixture (64^3 dense multilabel, relax):
   (8,16,16) 0.9 Mvox/s → (4,8,8) 1.5 → (2,4,8) 2.1, vs 0.138 for the
-  whole-volume kernel — (2,4,8) is the CPU default. On TPU the tile must
-  fill the (8, 128) sublane/lane register shape instead: (8,16,128) is
-  64KB per int32 working array, ~5 arrays ≈ 320KB of the ~16MB VMEM, so
-  a tile's whole round loop runs on-chip with room to double-buffer."""
+  whole-volume kernel."""
   from .. import tune
 
-  # explicit env > tuned/<device_kind>.json > backend default (ISSUE 19)
+  # explicit env > tuned/<device_kind>.json > default (ISSUE 19)
   spec = tune.resolve("IGNEOUS_CCL_TILE")
   if not spec:
-    return (
-      _DEFAULT_TILE_TPU if jax.default_backend() == "tpu"
-      else _DEFAULT_TILE
-    )
+    return _DEFAULT_TILE
   try:
     t = tuple(int(v) for v in spec.split(","))
   except ValueError:
@@ -225,53 +225,75 @@ def _tile_shape() -> Tuple[int, int, int]:
     raise ValueError(
       f"IGNEOUS_CCL_TILE must be 'tz,ty,tx' positive ints: {spec!r}"
     )
+  if t not in _TPU_TILES and jax.default_backend() == "tpu":
+    raise ValueError(
+      f"CCL tile {spec!r} is not verified on TPU (the (8,16,128) tile gave "
+      f"wrong components on a v5e); TPU tiles: {list(_TPU_TILES)}"
+    )
   return t
 
 
-def _ccl_engine() -> str:
-  """'lax' | 'pallas' for the tile-resolve stage. Pallas engages on real
-  TPU backends when the lowering is available; the lax path is the
-  portable default (and what the CPU bench host measures). Force with
-  IGNEOUS_CCL_ENGINE=lax|pallas (pallas on CPU runs in interpret mode —
-  correct but slow; for parity tests)."""
-  import os
-
-  override = knobs.get_str("IGNEOUS_CCL_ENGINE")
-  if override:
-    if override not in ("lax", "pallas"):
-      raise ValueError(
-        f"IGNEOUS_CCL_ENGINE must be 'lax' or 'pallas': {override!r}"
-      )
-    return override
-  from . import pallas_ccl
-
-  return (
-    "pallas"
-    if pallas_ccl.available() and jax.default_backend() == "tpu"
-    else "lax"
+def _tile_resolve(labt: jnp.ndarray, connectivity: int, algo: str):
+  """labt (T, tz, ty, tx) int32 tiles → per-voxel LOCAL flat index of its
+  tile-component's minimum voxel (background keeps its own index).
+  Every tile runs the same seg-cummin / neighbor-min / pointer-jump
+  round structure as _ccl_kernel with a per-tile active mask: a converged
+  tile freezes while stragglers keep iterating, and the loop exits when
+  the last tile converges. ops/pallas_ccl.tile_resolve has the same
+  contract (it does not compile for v5e; a test checks its parity)."""
+  T, tz, ty, tx = labt.shape
+  tsize = tz * ty * tx
+  fg = labt != 0
+  L0 = jnp.broadcast_to(
+    jnp.arange(tsize, dtype=jnp.int32).reshape(1, tz, ty, tx), labt.shape
   )
+
+  def cond(state):
+    _, active = state
+    return jnp.any(active)
+
+  def body(state):
+    L, active = state
+    Lp = L
+    for axis in (1, 2, 3):
+      Lp = jnp.minimum(
+        _seg_cummin(Lp, labt, axis, False),
+        _seg_cummin(Lp, labt, axis, True),
+      )
+    Lp = jnp.minimum(
+      Lp, _neighbor_min(Lp, labt, connectivity, axes=(1, 2, 3))
+    )
+    Lp = jnp.where(fg, jnp.minimum(L, Lp), L)
+    if algo == "scan":
+      flat = Lp.reshape(-1, tsize)
+      for _ in range(2):
+        flat = jnp.take_along_axis(flat, flat, axis=1)
+      Lp = flat.reshape(Lp.shape)
+    # per-tile early exit: converged tiles freeze (no further updates)
+    Lp = jnp.where(active[:, None, None, None], Lp, L)
+    return (Lp, jnp.any(Lp != L, axis=(1, 2, 3)))
+
+  L, _ = jax.lax.while_loop(cond, body, (L0, jnp.ones((T,), dtype=bool)))
+  return L
 
 
 @partial(
-  jax.jit, static_argnames=("connectivity", "algo", "tile", "engine")
+  jax.jit,
+  static_argnames=("connectivity", "algo", "tile"),
 )
 def _ccl_tiled_kernel(
   labels: jnp.ndarray,
   connectivity: int = 6,
   algo: str = "scan",
   tile: Tuple[int, int, int] = _DEFAULT_TILE,
-  engine: str = "lax",
 ):
   """labels (z, y, x) int32 → per-voxel TILE-LOCAL root as a global flat
   index over the tile-padded volume (background: int32 max sentinel).
 
   The volume is cut into (tz, ty, tx) tiles (clipped to the volume,
-  padded with background); every tile runs the same seg-cummin /
-  neighbor-min / pointer-jump round structure as _ccl_kernel but over
-  LOCAL indices, with a per-tile active mask: a converged tile freezes
-  while stragglers keep iterating, and the loop exits when the last tile
-  converges — rounds are bounded by tile tortuosity, not volume
-  tortuosity. Cross-tile merging happens host-side (_merge_tile_roots)."""
+  padded with background) that _tile_resolve resolves locally: rounds
+  are bounded by tile tortuosity, not volume tortuosity. Cross-tile
+  merging happens host-side (_merge_tile_roots)."""
   Z, Y, X = labels.shape
   tz, ty, tx = (min(t, s) for t, s in zip(tile, labels.shape))
   pz, py, px = (-Z) % tz, (-Y) % ty, (-X) % tx
@@ -293,46 +315,7 @@ def _ccl_tiled_kernel(
   )
   fg = labt != 0
   big = jnp.iinfo(jnp.int32).max
-
-  if engine == "pallas":
-    from . import pallas_ccl
-
-    L = pallas_ccl.tile_resolve(
-      labt, connectivity, interpret=jax.default_backend() != "tpu"
-    )
-  else:
-    L0 = jnp.broadcast_to(
-      jnp.arange(tsize, dtype=jnp.int32).reshape(1, tz, ty, tx), labt.shape
-    )
-
-    def cond(state):
-      _, active = state
-      return jnp.any(active)
-
-    def body(state):
-      L, active = state
-      Lp = L
-      for axis in (1, 2, 3):
-        Lp = jnp.minimum(
-          _seg_cummin(Lp, labt, axis, False),
-          _seg_cummin(Lp, labt, axis, True),
-        )
-      Lp = jnp.minimum(
-        Lp, _neighbor_min(Lp, labt, connectivity, axes=(1, 2, 3))
-      )
-      Lp = jnp.where(fg, jnp.minimum(L, Lp), L)
-      if algo == "scan":
-        flat = Lp.reshape(-1, tsize)
-        for _ in range(2):
-          flat = jnp.take_along_axis(flat, flat, axis=1)
-        Lp = flat.reshape(Lp.shape)
-      # per-tile early exit: converged tiles freeze (no further updates)
-      Lp = jnp.where(active[:, None, None, None], Lp, L)
-      return (Lp, jnp.any(Lp != L, axis=(1, 2, 3)))
-
-    L, _ = jax.lax.while_loop(
-      cond, body, (L0, jnp.ones((labt.shape[0],), dtype=bool))
-    )
+  L = _tile_resolve(labt, connectivity, algo)
 
   # local root -> global flat index of that root voxel (in padded space)
   g = jnp.take_along_axis(
@@ -419,13 +402,15 @@ def _ccl_tiled(
   labels_zyx: np.ndarray, connectivity: int, algo: str
 ) -> np.ndarray:
   """Device tiled resolve + host boundary merge → merged roots (z, y, x)."""
+  from ..observability import device as device_telemetry
+
   tile = _tile_shape()
-  roots = np.asarray(
-    _ccl_tiled_kernel(
-      jnp.asarray(labels_zyx), connectivity, algo=algo, tile=tile,
-      engine=_ccl_engine(),
-    )
-  )
+  roots = np.asarray(device_telemetry.run_solo(
+    f"ccl.tiled[{algo}]", _ccl_tiled_kernel, (jnp.asarray(labels_zyx),),
+    (labels_zyx.shape, connectivity, tile),
+    static=dict(connectivity=connectivity, algo=algo, tile=tile),
+    elements=int(labels_zyx.size), nbytes=int(labels_zyx.nbytes),
+  ))
   return _merge_tile_roots(roots, labels_zyx, connectivity, tile)
 
 
@@ -592,23 +577,21 @@ _BATCH_EXECUTORS = {}
 def _batch_executor(connectivity: int, mesh=None):
   algo = _device_algo()
   tile = _tile_shape()
-  engine = _ccl_engine()
   mesh_key = (
     None if mesh is None
     else (tuple(d.id for d in mesh.devices.flat), mesh.axis_names)
   )
-  key = (connectivity, algo, tile, engine, mesh_key)
+  key = (connectivity, algo, tile, mesh_key)
   if key not in _BATCH_EXECUTORS:
     from ..parallel.executor import BatchKernelExecutor
 
     _BATCH_EXECUTORS[key] = BatchKernelExecutor(
       partial(
-        _ccl_tiled_kernel, connectivity=connectivity, algo=algo,
-        tile=tile, engine=engine,
+        _ccl_tiled_kernel, connectivity=connectivity, algo=algo, tile=tile,
       ),
       mesh=mesh,
       name=f"ccl.tiled[{algo}]",
-      cache_variant=("ccl_tiled", connectivity, algo, tile, engine),
+      cache_variant=("ccl_tiled", connectivity, algo, tile),
     )
   return _BATCH_EXECUTORS[key]
 

@@ -2,9 +2,10 @@
 
 `ops/ccl.py`'s tiled label-propagation path cuts the volume into
 VMEM-sized tiles and resolves each tile locally before one host
-boundary-merge pass. This module is the Pallas engine for that local
-resolve (``IGNEOUS_CCL_ENGINE=pallas``; the lax fallback in ccl.py is
-the portable default — same dispatch pattern as ops/pallas_pooling.py).
+boundary-merge pass. This module is a Pallas candidate for that local
+resolve. No program path calls it: it does not compile for v5e at an
+(8, 16, 128) int32 tile (Mosaic: "Invalid vector register cast"), so
+ccl.py resolves with lax (``ccl._tile_resolve``) on every backend.
 
 Per grid program: one (tz, ty, tx) tile lives in VMEM and iterates a
 gather-free round — log-doubling segmented cummin along each axis
@@ -14,10 +15,10 @@ requested connectivity — inside an in-kernel ``while_loop``. That loop
 is the real per-tile early exit: each tile stops at ITS OWN fixpoint
 instead of the batched-lax path's max-over-tiles round count.
 
-Output contract matches ccl._ccl_tiled_kernel's lax engine exactly:
+Output contract matches ccl._tile_resolve exactly:
 every voxel holds the LOCAL flat index of its tile-component's minimum
 voxel (background voxels keep their own index; the caller masks them),
-so the two engines are interchangeable bit-for-bit.
+so the two are interchangeable bit-for-bit.
 
 Use ``tile_resolve(..., interpret=True)`` for CPU parity tests.
 """

@@ -222,27 +222,9 @@ def _pyramid(x, factors, method, sparse):
   """The jitted pyramid behind device telemetry (ISSUE 7): the solo-task
   device path (``downsample()``) ticks the same recompile ledger and
   emits the same device.compile/device.execute spans as the batched
-  executors — first call on a new input signature is the compile."""
-  from ..observability import device as device_telemetry
-
-  kernel = f"pooling.pyramid[{method}]"
-  leaves = x if isinstance(x, tuple) else (x,)
-  sig = (tuple((np.shape(a), str(np.asarray(a).dtype)) for a in leaves),
-         factors, sparse)
-  fresh = device_telemetry.LEDGER.note_signature(kernel, sig)
-  elements = sum(int(np.size(a)) for a in leaves)
-  span = (
-    device_telemetry.compile_span(kernel, device_telemetry._devices_of())
-    if fresh else
-    device_telemetry.execute_span(
-      kernel, elements=elements,
-      nbytes=sum(int(np.asarray(a).nbytes) for a in leaves),
-    )
-  )
-  with span:
-    outs = _jit_pyramid(x, factors, method, sparse)
-    jax.block_until_ready(outs)
-  return outs
+  executors."""
+  return _solo_pyramid(f"pooling.pyramid[{method}]", x, factors, method,
+                       sparse)
 
 
 def _fused_pyramid(x, factors, method, sparse, mip_from: int = 0):
@@ -254,27 +236,23 @@ def _fused_pyramid(x, factors, method, sparse, mip_from: int = 0):
   span. Callers that walk a varying mip range per invocation (the serve
   tier's ancestor synth) use this so the journal records which levels
   each fused dispatch produced."""
+  return _solo_pyramid(
+    f"pooling.fused_pyramid[{method}]", x, factors, method, sparse,
+    mip_from=int(mip_from), mip_to=int(mip_from) + len(factors),
+  )
+
+
+def _solo_pyramid(kernel, x, factors, method, sparse, **attrs):
   from ..observability import device as device_telemetry
 
-  kernel = f"pooling.fused_pyramid[{method}]"
   leaves = x if isinstance(x, tuple) else (x,)
   sig = (tuple((np.shape(a), str(np.asarray(a).dtype)) for a in leaves),
          factors, sparse)
-  fresh = device_telemetry.LEDGER.note_signature(kernel, sig)
-  elements = sum(int(np.size(a)) for a in leaves)
-  span = (
-    device_telemetry.compile_span(kernel, device_telemetry._devices_of())
-    if fresh else
-    device_telemetry.execute_span(
-      kernel, elements=elements,
-      nbytes=sum(int(np.asarray(a).nbytes) for a in leaves),
-      mip_from=int(mip_from), mip_to=int(mip_from) + len(factors),
-    )
+  return device_telemetry.run_solo(
+    kernel, _jit_pyramid, (x, factors, method, sparse), sig,
+    elements=sum(int(np.size(a)) for a in leaves),
+    nbytes=sum(int(np.asarray(a).nbytes) for a in leaves), **attrs,
   )
-  with span:
-    outs = _jit_pyramid(x, factors, method, sparse)
-    jax.block_until_ready(outs)
-  return outs
 
 
 @lru_cache(maxsize=None)
@@ -447,10 +425,9 @@ def _backend_is_cpu() -> bool:
   plats = os.environ.get("JAX_PLATFORMS", "")
   if plats:
     return plats.split(",")[0].strip().lower() == "cpu"
-  try:
-    return jax.default_backend() == "cpu"
-  except Exception:
-    return True  # no usable backend at all: host path is the only path
+  # a backend that fails to initialize raises: quietly pooling on the
+  # host would hide a chip that is there but unusable
+  return jax.default_backend() == "cpu"
 
 
 def _host_pool_threads() -> int:

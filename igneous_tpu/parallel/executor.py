@@ -23,13 +23,6 @@ from ..analysis import knobs
 from ..observability import device as device_telemetry
 from ..ops.pooling import _pyramid_impl
 
-# jax.shard_map went public in newer jax; this image ships 0.4.x where it
-# still lives under jax.experimental (same semantics, check_rep kwarg) —
-# resolve once so every executor builds on whichever the runtime has
-if hasattr(jax, "shard_map"):
-  _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax<0.6 images
-  from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "chunks") -> Mesh:
@@ -187,16 +180,10 @@ class BatchKernelExecutor:
     # collectives, but their internal scan/while carries start from
     # literals, which the varying-manual-axes checker rejects under
     # shard_map (carry input unvarying vs output varying)
-    try:
-      fn = _shard_map(
-        batched, mesh=self.mesh,
-        in_specs=in_specs, out_specs=out_specs, check_vma=False,
-      )
-    except TypeError:  # older jax: the parameter was named check_rep
-      fn = _shard_map(
-        batched, mesh=self.mesh,
-        in_specs=in_specs, out_specs=out_specs, check_rep=False,
-      )
+    fn = jax.shard_map(
+      batched, mesh=self.mesh,
+      in_specs=in_specs, out_specs=out_specs, check_vma=False,
+    )
     # lint: allow=IGN201 AOT lower+compile cached by signature at call site
     return jax.jit(fn)
 
@@ -339,7 +326,7 @@ class ChunkExecutor:
     else:
       mip_spec = tuple(P(self.axis) for _ in factors)
     out_spec = (mip_spec, P())
-    fn = _shard_map(
+    fn = jax.shard_map(
       per_shard, mesh=self.mesh, in_specs=(in_spec,), out_specs=out_spec
     )
     # lint: allow=IGN201 AOT lower+compile cached by signature at call site
@@ -370,31 +357,25 @@ class ChunkExecutor:
       raise ValueError(f"expected {self.planes} plane(s), got {len(arrs)}")
     sig = ("global",) + tuple((a.shape, str(a.dtype)) for a in arrs)
     # the persistent cache (ISSUE 19) prefers the AOT route so a warm
-    # worker skips the compile entirely; any failure (AOT executables
-    # and global arrays interact badly across some versions) falls
-    # through to the plain-jit path below, which stays the default when
-    # no cache is configured
+    # worker skips the compile entirely (load_or_compile falls back to
+    # compiling on a failed fetch); the plain-jit path below stays the
+    # default when no cache is configured
     if compile_cache.get_active() is not None:
       compiled = self._compiled.get(sig)
-      try:
-        if compiled is None:
-          compiled = compile_cache.load_or_compile(
-            self.name, sig, self.mesh,
-            lambda: self._fn.lower(tuple(arrs)).compile(),
-            variant=self.cache_variant + ("global",),
-          )
-          self._compiled[sig] = compiled
-        with device_telemetry.execute_span(
-          self.name, elements=device_telemetry.elements_of(arrs),
-          mesh=self.mesh, **self.span_attrs,
-        ):
-          out = compiled(tuple(arrs))
-          jax.block_until_ready(out)
-        return out
-      except Exception:
-        from ..observability import metrics
-
-        metrics.incr("device.compile_cache.error")
+      if compiled is None:
+        compiled = compile_cache.load_or_compile(
+          self.name, sig, self.mesh,
+          lambda: self._fn.lower(tuple(arrs)).compile(),
+          variant=self.cache_variant + ("global",),
+        )
+        self._compiled[sig] = compiled
+      with device_telemetry.execute_span(
+        self.name, elements=device_telemetry.elements_of(arrs),
+        mesh=self.mesh, **self.span_attrs,
+      ):
+        out = compiled(tuple(arrs))
+        jax.block_until_ready(out)
+      return out
     # multihost default keeps the plain jit; first-call-per-signature
     # still ticks the recompile ledger and labels as compile
     fresh = device_telemetry.LEDGER.note_signature(self.name, sig)

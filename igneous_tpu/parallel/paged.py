@@ -66,7 +66,7 @@ from ..ops.pooling import (
   _split_u64_planes,
   _to_device_layout,
 )
-from .executor import BatchKernelExecutor, LRUCache, _shard_map, make_mesh
+from .executor import BatchKernelExecutor, LRUCache, make_mesh
 
 from .. import tune
 from ..analysis import knobs
@@ -458,23 +458,19 @@ _PAGED_CCL_EXECUTORS = {}
 
 
 def _paged_ccl_executor(connectivity: int, mesh=None):
-  from ..ops.ccl import (
-    _ccl_engine, _ccl_tiled_kernel, _device_algo, _tile_shape,
-  )
+  from ..ops.ccl import _ccl_tiled_kernel, _device_algo, _tile_shape
 
   algo = _device_algo()
   tile = _tile_shape()
-  engine = _ccl_engine()
-  key = (connectivity, algo, tile, engine, _mesh_key(mesh))
+  key = (connectivity, algo, tile, _mesh_key(mesh))
   if key not in _PAGED_CCL_EXECUTORS:
     _PAGED_CCL_EXECUTORS[key] = BatchKernelExecutor(
       partial(
-        _ccl_tiled_kernel, connectivity=connectivity, algo=algo,
-        tile=tile, engine=engine,
+        _ccl_tiled_kernel, connectivity=connectivity, algo=algo, tile=tile,
       ),
       mesh=mesh,
       name=f"ccl.paged[{algo}]",
-      cache_variant=("ccl_paged", connectivity, algo, tile, engine),
+      cache_variant=("ccl_paged", connectivity, algo, tile),
     )
   return _PAGED_CCL_EXECUTORS[key]
 
@@ -693,16 +689,10 @@ class PagedGlobalRunner:
       ),
     )
     out_specs = jax.tree.map(lambda _: P(self.axis), out_shape)
-    try:
-      fn = _shard_map(
-        batched, mesh=self.mesh, in_specs=(P(self.axis),),
-        out_specs=out_specs, check_vma=False,
-      )
-    except TypeError:  # older jax: the parameter was named check_rep
-      fn = _shard_map(
-        batched, mesh=self.mesh, in_specs=(P(self.axis),),
-        out_specs=out_specs, check_rep=False,
-      )
+    fn = jax.shard_map(
+      batched, mesh=self.mesh, in_specs=(P(self.axis),),
+      out_specs=out_specs, check_vma=False,
+    )
     # lint: allow=IGN201 AOT lower+compile cached by signature at call site
     return jax.jit(fn)
 
@@ -715,30 +705,26 @@ class PagedGlobalRunner:
     leaves = jax.tree.leaves(tree)
     sig = tuple((tuple(a.shape), str(a.dtype)) for a in leaves)
     # persistent cache path (ISSUE 19): a warm worker fetches the AOT
-    # executable instead of compiling; any failure falls through to the
-    # plain-jit path below (the default when no cache is configured)
+    # executable instead of compiling (load_or_compile falls back to
+    # compiling on a failed fetch); the plain-jit path below is the
+    # default when no cache is configured
     if compile_cache.get_active() is not None:
       compiled = self._aot.get(sig)
-      try:
-        if compiled is None:
-          compiled = compile_cache.load_or_compile(
-            self.name, sig, self.mesh,
-            lambda: self._make(tree).lower(tree).compile(),
-            variant=self.cache_variant,
-          )
-          self._aot[sig] = compiled
-        with device_telemetry.execute_span(
-          self.name,
-          elements=sum(int(np.prod(a.shape)) for a in leaves),
-          mesh=self.mesh,
-        ):
-          out = compiled(tree)
-          jax.block_until_ready(out)
-        return out
-      except Exception:
-        from ..observability import metrics
-
-        metrics.incr("device.compile_cache.error")
+      if compiled is None:
+        compiled = compile_cache.load_or_compile(
+          self.name, sig, self.mesh,
+          lambda: self._make(tree).lower(tree).compile(),
+          variant=self.cache_variant,
+        )
+        self._aot[sig] = compiled
+      with device_telemetry.execute_span(
+        self.name,
+        elements=sum(int(np.prod(a.shape)) for a in leaves),
+        mesh=self.mesh,
+      ):
+        out = compiled(tree)
+        jax.block_until_ready(out)
+      return out
     if sig not in self._fns:
       self._fns[sig] = self._make(tree)
     fresh = device_telemetry.LEDGER.note_signature(self.name, sig)

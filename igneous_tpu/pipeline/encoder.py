@@ -1,6 +1,6 @@
 """Parallel chunk encode/upload with deterministic bytes.
 
-The e2e profile (BENCH_r05, VERDICT weak #3) shows chunk encode+compress
+The e2e profile shows chunk encode+compress
 +put as a serial tail on every task: each produced mip's chunks were
 encoded and written one after another on the compute thread. This module
 moves that tail onto a persistent thread pool.

@@ -11,6 +11,7 @@ is outright unsafe.
 
 from __future__ import annotations
 
+import functools
 import multiprocessing as mp
 import os
 from typing import Iterable, Optional
@@ -54,6 +55,15 @@ def _worker_init(pool_threads: int):
   the reference's cv2.setNumThreads(0),
   /root/reference/igneous/tasks/image/image.py:177-180)."""
   os.environ.setdefault("IGNEOUS_POOL_THREADS", str(pool_threads))
+
+
+def _in_worker(fn, payload):
+  """Pool-worker call: a worker that landed on the CPU backend fails
+  here, and the failure reaches the parent with the result."""
+  from ..observability.device import require_worker_backend
+
+  require_worker_backend()
+  return fn(payload)
 
 
 class LocalTaskQueue:
@@ -152,7 +162,8 @@ class LocalTaskQueue:
       ) as pool:
         if self.max_deliveries is None:
           for _ in pool.imap_unordered(
-            _execute_payload, payloads, chunksize=1
+            functools.partial(_in_worker, _execute_payload), payloads,
+            chunksize=1,
           ):
             self.inserted += 1
             self.completed += 1
@@ -160,13 +171,11 @@ class LocalTaskQueue:
             if self._draining():
               break  # pool __exit__ terminates; unconsumed payloads stay
         else:
-          import functools
-
           runner = functools.partial(
             _execute_payload_contained, max_deliveries=self.max_deliveries
           )
           for payload, err in pool.imap_unordered(
-            runner, payloads, chunksize=1
+            functools.partial(_in_worker, runner), payloads, chunksize=1
           ):
             self.inserted += 1
             if err is not None:

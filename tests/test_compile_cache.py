@@ -161,7 +161,8 @@ def test_corrupt_entry_falls_back_to_compile(cache_root):
   assert stats["puts"] == 1  # the self-heal re-put
   assert os.path.exists(path)  # good copy back in place
   device_mod.reset()
-  assert cache.get(meta) is not None  # and it verifies
+  # and it verifies, loaded onto the devices it was compiled for
+  assert cache.get(meta, list(make_mesh(2).devices.flat)) is not None
 
 
 def test_meta_mismatch_rejected(cache_root):
@@ -186,10 +187,11 @@ def test_concurrent_writers_converge(cache_root):
   """Write-once put: the second writer of the same key backs off; exactly
   one complete entry remains and it verifies."""
   cache, meta, key, path, _ = _seed_entry(cache_root)
-  compiled, _header = cache.get(meta)
+  devices = list(make_mesh(2).devices.flat)
+  compiled, _header = cache.get(meta, devices)
   assert cache.put(meta, compiled, 1.0) is False  # already exists
   device_mod.reset()
-  assert cache.get(meta) is not None
+  assert cache.get(meta, devices) is not None
   assert device_mod.LEDGER.compile_cache["corrupt"] == 0
 
 
@@ -295,6 +297,27 @@ def test_tuned_config_applies_and_env_wins(cache_root, monkeypatch):
   assert _line_block() == 64
 
 
+@pytest.mark.parametrize("source", ["env", "tuned"])
+def test_unverified_ccl_tile_refused_on_tpu(cache_root, monkeypatch, source):
+  """(8,16,128) gave wrong components on a v5e: on a TPU no tile outside
+  ccl._TPU_TILES is taken, from the env or a tuned config, and the tuner
+  sweeps none; the CPU keeps taking it."""
+  import jax
+
+  from igneous_tpu.ops import ccl
+
+  monkeypatch.delenv("IGNEOUS_CCL_TILE", raising=False)
+  if source == "env":
+    monkeypatch.setenv("IGNEOUS_CCL_TILE", "8,16,128")
+  else:
+    _write_tuned(cache_root, {"IGNEOUS_CCL_TILE": "8,16,128"})
+  assert ccl._tile_shape() == (8, 16, 128)
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  with pytest.raises(ValueError, match="not verified on TPU"):
+    ccl._tile_shape()
+  assert tune.candidates("tpu")["IGNEOUS_CCL_TILE"] == [""]
+
+
 def test_tune_config_root_precedence(cache_root, tmp_path, monkeypatch):
   """IGNEOUS_TUNE_CONFIG outranks IGNEOUS_COMPILE_CACHE as config root."""
   other = f"file://{tmp_path}/tuned_only"
@@ -346,3 +369,69 @@ def test_fleet_rollup_reports_cache_stats(cache_root):
   assert summary["compile_cache"]["saved_s"] > 0.0
   lines = "\n".join(device_mod.render_devices(ledgers))
   assert "compile cache" in lines and "1 hits" in lines
+
+
+# -- JAX's own persistent cache placement -----------------------------------
+
+@pytest.mark.parametrize("where", ["env", "checkout", "installed"])
+def test_place_jax_cache(monkeypatch, where):
+  """A set JAX_COMPILATION_CACHE_DIR stays as JAX read it; else a checkout
+  caches in <checkout>/.jax_cache and an installed package keeps JAX's
+  default (no cache)."""
+  import jax
+
+  keys = ("jax_compilation_cache_dir",
+          "jax_persistent_cache_min_compile_time_secs")
+  before = {k: getattr(jax.config, k) for k in keys}
+  monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                     raising=False)
+  monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+  repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  try:
+    if where == "env":
+      # JAX read the variable itself at import; placement leaves it alone
+      monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+      jax.config.update("jax_compilation_cache_dir", "/elsewhere/cache")
+      assert cc.place_jax_cache() == "/elsewhere/cache"
+    elif where == "checkout":
+      assert cc.checkout_root() == repo
+      assert cc.place_jax_cache() == os.path.join(repo, ".jax_cache")
+    else:
+      monkeypatch.setattr(cc, "checkout_root", lambda: None)
+      assert cc.place_jax_cache() is None
+      assert jax.config.jax_compilation_cache_dir == before[keys[0]]
+    if where != "installed":
+      assert jax.config.jax_compilation_cache_dir == cc.place_jax_cache()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+  finally:
+    for k, v in before.items():
+      jax.config.update(k, v)
+
+
+def test_place_jax_cache_before_jax_import(tmp_path):
+  """Before JAX is imported placement only sets the variables JAX reads
+  at import: the CLI's group callback imports no JAX for commands that
+  never compile."""
+  import subprocess
+  import sys
+
+  repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  env = {k: v for k, v in os.environ.items()
+         if not k.startswith("JAX_COMPILATION_CACHE")
+         and k != "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"}
+  env["PYTHONPATH"] = repo
+  code = (
+    "import sys\n"
+    "from igneous_tpu.compile_cache import place_jax_cache\n"
+    "d = place_jax_cache()\n"
+    "assert 'jax' not in sys.modules\n"
+    "import jax\n"
+    "print(d == jax.config.jax_compilation_cache_dir,\n"
+    "      jax.config.jax_persistent_cache_min_compile_time_secs)\n"
+  )
+  out = subprocess.run(
+    [sys.executable, "-c", code], env=env, cwd=tmp_path,
+    capture_output=True, text=True, timeout=120,
+  )
+  assert out.returncode == 0, out.stderr
+  assert out.stdout.split() == ["True", "0.0"]

@@ -88,16 +88,28 @@ def test_ccl_degenerate_volumes(monkeypatch):
     assert n == 1 and (out == 1).all()
 
 
-def test_ccl_pallas_engine_parity(rng, monkeypatch):
-  """IGNEOUS_CCL_ENGINE=pallas (interpret mode on CPU) must produce the
-  identical roots as the lax engine — same fixpoint, same numbering."""
-  monkeypatch.setenv("IGNEOUS_CCL_BACKEND", "device")
-  lab = _random_labels(rng, (19, 14, 9), np.uint32)
-  outs = {}
-  for engine in ("lax", "pallas"):
-    monkeypatch.setenv("IGNEOUS_CCL_ENGINE", engine)
-    outs[engine] = connected_components(lab, connectivity=6)
-  assert np.array_equal(outs["lax"], outs["pallas"])
+def test_ccl_pallas_tile_resolve_parity(rng):
+  """The Pallas tile resolve (interpret mode, asked for here) must give
+  the identical local roots as the lax resolve — same fixpoint, same
+  numbering."""
+  import jax
+
+  from igneous_tpu.ops import pallas_ccl
+  from igneous_tpu.ops.ccl import _dense_relabel, _tile_resolve
+
+  lab = _random_labels(rng, (16, 16, 8), np.uint32)
+  zyx = _dense_relabel(lab).transpose(2, 1, 0)
+  # (z, y, x) = (8, 16, 16) cut into (2, 4, 8) tiles
+  labt = np.ascontiguousarray(
+    zyx.reshape(4, 2, 4, 4, 2, 8).transpose(0, 2, 4, 1, 3, 5)
+    .reshape(32, 2, 4, 8)
+  )
+  lax_roots = np.asarray(jax.jit(
+    lambda t: _tile_resolve(t, 6, "scan"))(labt))
+  pallas_roots = np.asarray(
+    pallas_ccl.tile_resolve(labt, 6, interpret=True))
+  fg = labt != 0
+  assert np.array_equal(lax_roots[fg], pallas_roots[fg])
 
 
 def test_ccl_tile_smaller_than_volume_and_larger(rng, monkeypatch):
@@ -140,6 +152,22 @@ def test_edt_device_vs_host_sweep(rng, monkeypatch, anisotropy):
     # backends order the parabola arithmetic differently; ops/edt.py
     # documents the contract as per-backend bitwise determinism)
     np.testing.assert_allclose(dev, host, rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize(
+  "anisotropy", [(1.0, 1.0, 1.0), (8.0, 8.0, 40.0), (4.5, 4.5, 33.0)]
+)
+def test_edt_device_equals_native(rng, monkeypatch, anisotropy):
+  """The device EDT rounds each distance once, like the native kernel's
+  double arithmetic, so skeletons forged on either field are identical."""
+  # dense foreground: long runs, so the y and z passes carry heights
+  # that normalizing by w^2 cannot represent exactly
+  lab = (rng.random((40, 40, 16)) < 0.97).astype(np.uint8)
+  out = {}
+  for backend in ("device", "native"):
+    monkeypatch.setenv("IGNEOUS_EDT_BACKEND", backend)
+    out[backend] = edt_mod.edt(lab, anisotropy, black_border=True)
+  assert np.array_equal(out["device"], out["native"])
 
 
 def test_edt_device_black_border_and_determinism(rng, monkeypatch):

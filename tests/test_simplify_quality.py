@@ -14,7 +14,7 @@ zmesh simplifier call at reference mesh.py:371-383):
   * the LOD ladder generate_lods() builds for multires meshes keeps
     shrinking by ~the requested reduction per level.
 
-Failing any budget fails CI. BASELINE.md records the measured LOD table.
+Failing any budget fails CI.
 """
 
 import numpy as np
