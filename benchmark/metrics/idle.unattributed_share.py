@@ -1,0 +1,11 @@
+"""Percent of the traced window in which the device sat idle while the
+worker loop was in none of its phase spans (acquire, prefetch wait,
+fetch, dispatch, finish). Mean over the cell's chips (profiler
+trace)."""
+
+from benchmark import span_reduce
+
+
+def read(ctx):
+  return span_reduce.idle_share(span_reduce.for_run(ctx, __file__),
+                                span_reduce.UNATTRIBUTED)

@@ -371,9 +371,6 @@ _knob("IGNEOUS_METRICS_TEXTFILE", "str", None,
 _knob("IGNEOUS_PROFILE_DIR", "str", None,
       "jax.profiler capture directory (unset = profiling inert)",
       "trace / metrics / profile")
-_knob("IGNEOUS_TPU_PROFILE_DIR", "str", None,
-      "legacy alias of `IGNEOUS_PROFILE_DIR`",
-      "trace / metrics / profile")
 _knob("IGNEOUS_PROFILE_EVERY", "int", 0,
       "sample a capture every Nth device dispatch (0 disables)",
       "trace / metrics / profile")

@@ -31,10 +31,10 @@ PASS_ID = "telemetry"
 
 SUBSYSTEMS = frozenset({
   "autoscale", "campaign", "chaos", "chunk_cache", "device", "dlq",
-  "drain", "fleet", "health", "infer", "integrity", "journal",
+  "drain", "fleet", "health", "infer", "integrity", "journal", "lease",
   "metrics", "pipeline", "queue", "retries", "rollup", "serve", "sim",
   "slo", "speculation", "steal", "storage", "tasks", "transfer",
-  "worker", "zombie",
+  "volume", "worker", "zombie",
 })
 
 # the telemetry implementation itself forwards caller-supplied names
@@ -128,7 +128,7 @@ def _call_kind(node: ast.Call) -> Optional[str]:
     base = fn.value
     base_name = base.id if isinstance(base, ast.Name) else \
       base.attr if isinstance(base, ast.Attribute) else ""
-    if base_name in ("telemetry", "metrics", "tele"):
+    if base_name in ("telemetry", "metrics", "tele", "trace", "_trace"):
       name = fn.attr
   return KIND_OF.get(name) if name else None
 

@@ -54,7 +54,6 @@ from . import (
 from .metrics import (
   StageTimes,
   counters_snapshot,
-  device_trace,
   emit_counters,
   gauge_max,
   gauge_set,
@@ -75,7 +74,7 @@ from .metrics import (
 __all__ = [
   "autoscale", "device", "fleet", "health", "journal", "perfetto",
   "prom", "replay", "rollup", "sim", "trace",
-  "StageTimes", "counters_snapshot", "device_trace", "emit_counters",
+  "StageTimes", "counters_snapshot", "emit_counters",
   "gauge_max", "gauge_set", "gauges_snapshot", "histograms_snapshot",
   "incr", "observe", "observe_quiet", "queue_eta", "reset_all",
   "reset_counters", "stage",

@@ -13,7 +13,9 @@ folklore, not a number. This module is the instrument:
   nest under whatever task/stage trace context is active on the calling
   thread (PR 5), so ``fleet trace`` and the Perfetto export show the
   device work inside the task that caused it — on its own per-device
-  track.
+  track. Each is a ``trace.span``, so a profiler trace also shows it as
+  ``igneous.device.<phase>`` with its ``kernel`` (and ``bytes``) beside
+  the device's own operations.
 * **Compile-cache / shape-churn ledger** — distinct compiled signatures
   per kernel are counted; ``device.recompiles`` increments exactly once
   per NEW signature (the ragged-batching baseline number), and the
@@ -37,8 +39,8 @@ folklore, not a number. This module is the instrument:
 
 Everything here must be safe on accelerator-less hosts and cost nothing
 when idle: ledger updates are a dict update under one lock, span records
-only allocate when a sampled trace context is active, and the profiler
-is inert unless explicitly triggered.
+only allocate when a sampled trace context is active (or on the worker
+trace), and the profiler is inert unless explicitly triggered.
 """
 
 from __future__ import annotations
@@ -424,15 +426,18 @@ def _devices_of(mesh=None) -> List[str]:
 
 
 def record_span(name: str, seconds: float, **attrs) -> None:
-  """A pre-measured device span carrying kernel/device/byte attrs for
-  the Perfetto device tracks. Under a sampled task/stage context it
-  nests there (``fleet trace`` shows the device work inside the task
-  that caused it); otherwise it lands on the worker trace — lease-round
-  dispatches and driver-run batched workloads happen outside any task
-  span, and their device timeline must not vanish for it."""
+  """A pre-measured device span (a compile-cache fetch), journaled as
+  the compile/execute/transfer spans below are: under a sampled
+  task/stage context it nests there (``fleet trace`` shows the device
+  work inside the task that caused it); otherwise it lands on the worker
+  trace — lease-round dispatches and batched workloads happen outside
+  any task span, and their device timeline must not vanish for it. The
+  spans below are ``trace.span(..., worker=True)`` regions, on
+  the profiler's clock too."""
   ctx = trace.current()
   if ctx is not None:
     if ctx.sampled:
+      # lint: allow=IGN503 forwards the caller's device span name
       trace.record_span(name, seconds, **attrs)
     return  # unsampled task: honor its sampling verdict
   if trace.tracing_enabled():
@@ -443,15 +448,16 @@ def record_span(name: str, seconds: float, **attrs) -> None:
 def compile_span(kernel: str, devices: List[str]) -> Iterator[None]:
   """Time one XLA compilation (lower+compile, or the first traced call
   of a fresh signature) and account it to the ledger."""
+  sp = trace.span("device.compile", worker=True, kernel=kernel,
+                  device=devices[0] if devices else None)
   t0 = time.perf_counter()
   try:
-    yield
+    with sp:
+      yield
   finally:
     dt = time.perf_counter() - t0
     LEDGER.record_compile(kernel, dt)
     metrics.observe_quiet("device.compile.s", dt)
-    record_span("device.compile", dt, kernel=kernel,
-                device=devices[0] if devices else None)
 
 
 @contextlib.contextmanager
@@ -464,17 +470,18 @@ def execute_span(kernel: str, elements: int = 0, nbytes: int = 0,
   Extra keyword ``attrs`` ride onto the emitted ``device.execute`` span
   verbatim (e.g. the fused pyramid kernel's ``mip_from``/``mip_to``)."""
   devices = _devices_of(mesh)
+  sp = trace.span("device.execute", worker=True, kernel=kernel,
+                  elements=elements, device=devices[0] if devices else None,
+                  devices=len(devices), **attrs)
   t0 = time.perf_counter()
   try:
-    yield
+    with sp:
+      yield
   finally:
     dt = time.perf_counter() - t0
     LEDGER.record_execute(kernel, dt, elements=elements, nbytes=nbytes,
                           devices=devices)
     metrics.observe_quiet("device.execute.s", dt)
-    record_span("device.execute", dt, kernel=kernel, elements=elements,
-                device=devices[0] if devices else None,
-                devices=len(devices), **attrs)
     maybe_sample_profile()
 
 
@@ -502,15 +509,16 @@ def transfer_span(direction: str, nbytes: int, kernel: str = "",
   """Time one host<->device transfer (``direction`` is "h2d" or "d2h")
   with its byte count."""
   devices = _devices_of(mesh)
+  sp = trace.span(f"device.{direction}", worker=True, kernel=kernel or None,
+                  bytes=int(nbytes), device=devices[0] if devices else None)
   t0 = time.perf_counter()
   try:
-    yield
+    with sp:
+      yield
   finally:
     dt = time.perf_counter() - t0
     LEDGER.record_transfer(direction, nbytes, dt)
     metrics.observe_quiet(f"device.{direction}.s", dt)
-    record_span(f"device.{direction}", dt, kernel=kernel or None,
-                bytes=int(nbytes), device=devices[0] if devices else None)
 
 
 def nbytes_of(tree) -> int:

@@ -15,15 +15,12 @@ from __future__ import annotations
 
 import contextlib
 import json
-import os
 import threading
 import time
 from collections import defaultdict
 from typing import Dict, Iterator, Optional
 
 from . import trace
-
-from ..analysis import knobs
 
 _local = threading.local()
 
@@ -251,50 +248,6 @@ def stage(name: str):
     dt = time.perf_counter() - t0
     for st in _stack():
       st.add(name, dt)
-
-
-@contextlib.contextmanager
-def device_trace(logdir: Optional[str] = None):
-  """jax.profiler trace around a device-heavy region.
-
-  Gated on ``IGNEOUS_PROFILE_DIR`` (legacy ``IGNEOUS_TPU_PROFILE_DIR``
-  still honored) so it is INERT by default — workers without profiling
-  infrastructure pay one env read. Logdirs are namespaced per worker
-  process (hostname-pid): concurrent workers sharing one profile dir
-  must not interleave their TensorBoard event files. ``stop_trace`` is
-  exception-safe twice over: it runs from a ``finally`` so the region's
-  exception still stops the profiler, and a stop failure (profiler
-  already torn down, backend gone mid-drain) never masks — or adds to —
-  the region's own outcome."""
-  logdir = (
-    logdir
-    or knobs.get_str("IGNEOUS_PROFILE_DIR")
-    or knobs.get_str("IGNEOUS_TPU_PROFILE_DIR")
-  )
-  if not logdir:
-    yield
-    return
-  import socket
-
-  import jax
-
-  host = socket.gethostname().split(".")[0] or "worker"
-  logdir = os.path.join(logdir, f"{host}-{os.getpid()}")
-  try:
-    jax.profiler.start_trace(logdir)
-  except Exception:
-    # a second start (nested regions, a concurrent triggered capture)
-    # raises inside jax; profiling is diagnostics, not correctness
-    incr("device.profile.start_failed")
-    yield
-    return
-  try:
-    yield
-  finally:
-    try:
-      jax.profiler.stop_trace()
-    except Exception:
-      incr("device.profile.stop_failed")
 
 
 def timed_poll_hooks(verbose: bool = True):

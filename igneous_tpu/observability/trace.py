@@ -12,9 +12,15 @@ Cost model: span records are plain dicts appended to per-thread buffers
 (one tiny uncontended lock per thread — no global lock on the hot path),
 drained in batches by the journal. ``IGNEOUS_TRACE_SAMPLE`` (default 1.0)
 gates allocation: at 0 no trace objects exist at all (task payloads carry
-no trace, every span call is a thread-local None check), between 0 and 1
-trace identity is always minted (lineage stays intact) but only the
-sampled fraction records spans.
+no trace, no span writes a record), between 0 and 1 trace identity is
+always minted (lineage stays intact) but only the sampled fraction records
+spans.
+
+Profiler clock: every :class:`span` is also a ``jax.profiler``
+annotation named ``igneous.<name>``, whatever the sampling, so a profiler
+trace (``igneous profile capture``, or any ``jax.profiler`` session)
+places the worker loop, the downloads and the device dispatches on the
+device's timeline.
 """
 
 from __future__ import annotations
@@ -22,6 +28,7 @@ from __future__ import annotations
 import contextlib
 import os
 import random
+import sys
 import threading
 import time
 import uuid
@@ -170,36 +177,107 @@ def activate(ctx: Optional[SpanContext]) -> Iterator[Optional[SpanContext]]:
     _TLS.ctx = prev
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs) -> Iterator[Optional[str]]:
-  """Record a wall-clock span under the active context (no-op when no
-  sampled context is active). Nested spans parent to this one."""
-  ctx = getattr(_TLS, "ctx", None)
-  if ctx is None or not ctx.sampled:
-    yield None
-    return
-  span_id = new_id()
-  parent = ctx.span_id
-  ctx.span_id = span_id
-  ts = time.time()
-  t0 = time.perf_counter()
-  error = None
-  try:
-    yield span_id
-  except BaseException as e:
-    error = type(e).__name__
-    raise
-  finally:
-    ctx.span_id = parent
+PROFILER_PREFIX = "igneous."
+_ANNOTATION: list = []  # [jax.profiler.TraceAnnotation], resolved once
+
+
+def _annotation_type():
+  """``jax.profiler.TraceAnnotation``, or None in a process that has not
+  imported JAX: no profiler can run there, and a span must not pull JAX
+  into a process (a queue CLI, the simulator) that never needs it."""
+  if not _ANNOTATION:
+    if "jax" not in sys.modules:
+      return None
+    from jax.profiler import TraceAnnotation
+
+    _ANNOTATION.append(TraceAnnotation)
+  return _ANNOTATION[0]
+
+
+def _scalars(attrs: dict) -> dict:
+  return {k: v for k, v in attrs.items() if isinstance(v, (int, float, str))}
+
+
+class span:
+  """A timed region named ``name``, as a context manager.
+
+  The region is always on the profiler's clock: it enters
+  ``jax.profiler.TraceAnnotation("igneous." + name)`` with the scalar
+  attributes as stats, sampled or not, so a ``jax.profiler`` trace shows
+  it beside the device's operations. With the profiler off that costs
+  the annotation's construction.
+
+  The journal record is written under a sampled context, where nested
+  spans parent to this one (``with span(...) as span_id``). With
+  ``worker=True`` and no context active on the thread, it is written
+  onto the worker trace instead (lease rounds, the device work they
+  dispatch); an unsampled context writes nothing.
+
+  ``set`` adds attributes known only inside the region (they reach the
+  journal record and the profiler event); ``drop`` keeps the region off
+  the journal (the profiler event stays)."""
+
+  __slots__ = ("name", "attrs", "worker", "_ann", "_ctx", "_id", "_parent",
+               "_ts", "_t0", "_keep")
+
+  def __init__(self, name: str, worker: bool = False, **attrs):
+    self.name = name
+    self.worker = worker
+    self.attrs = attrs
+    self._keep = True
+    self._ann = None
+    self._ctx = None
+    self._id = None
+
+  def set(self, **attrs) -> None:
+    self.attrs.update(attrs)
+    if self._ann is not None:
+      scalars = _scalars(attrs)
+      if scalars:
+        self._ann.set_metadata(**scalars)
+
+  def drop(self) -> None:
+    self._keep = False
+
+  def __enter__(self) -> Optional[str]:
+    ann_type = _annotation_type()
+    if ann_type is not None:
+      self._ann = ann_type(PROFILER_PREFIX + self.name,
+                           **_scalars(self.attrs))
+      self._ann.__enter__()
+    ctx = getattr(_TLS, "ctx", None)
+    if ctx is not None:
+      if ctx.sampled:
+        self._ctx = ctx
+        self._id = new_id()
+        self._parent = ctx.span_id
+        ctx.span_id = self._id
+    elif self.worker and tracing_enabled():
+      self._id = new_id()
+      self._parent = None
+    self._ts = time.time()
+    self._t0 = time.perf_counter()
+    return self._id if self._ctx is not None else None
+
+  def __exit__(self, exc_type, exc, tb) -> None:
+    dur = time.perf_counter() - self._t0
+    if self._ann is not None:
+      self._ann.__exit__(exc_type, exc, tb)
+      self._ann = None
+    ctx = self._ctx
+    if ctx is not None:
+      ctx.span_id = self._parent
+    if self._id is None or not self._keep:
+      return
     rec = {
-      "trace": ctx.trace_id, "span": span_id, "parent": parent,
-      "name": name, "ts": ts,
-      "dur": time.perf_counter() - t0,
+      "trace": ctx.trace_id if ctx is not None else _WORKER_TRACE,
+      "span": self._id, "parent": self._parent,
+      "name": self.name, "ts": self._ts, "dur": dur,
     }
-    if error:
-      rec["error"] = error
-    if attrs:
-      rec.update(attrs)
+    if exc_type is not None:
+      rec["error"] = exc_type.__name__
+    if self.attrs:
+      rec.update(self.attrs)
     _record(rec)
 
 

@@ -9,6 +9,7 @@ scalar statistics (voxel counts, histograms) without host round-trips.
 
 from __future__ import annotations
 
+import re
 from collections import OrderedDict
 from functools import partial
 from typing import List, Optional, Sequence, Tuple
@@ -23,6 +24,28 @@ from ..analysis import knobs
 from ..observability import device as device_telemetry
 from ..ops.pooling import _pyramid_impl
 
+
+
+def program_name(kernel: str) -> str:
+  """The Python name a kernel's compiled program is built from:
+  ``pooling.pyramid[average]`` -> ``pooling_pyramid_average``. JAX names
+  the program's module ``jit_<program_name>``, which is how a profiler
+  trace finds the kernel's device time by name."""
+  return re.sub(r"\W+", "_", kernel).strip("_") or "kernel"
+
+
+def _named_jit(fn, kernel: str):
+  """``jax.jit`` of ``fn`` under the kernel's stable program name, with
+  its operations inside ``jax.named_scope`` of the same name."""
+  name = program_name(kernel)
+
+  def program(*args):
+    with jax.named_scope(name):
+      return fn(*args)
+
+  program.__name__ = program.__qualname__ = name
+  # lint: allow=IGN201 AOT lower+compile cached by signature at call site
+  return jax.jit(program)
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "chunks") -> Mesh:
@@ -135,7 +158,12 @@ class BatchKernelExecutor:
     self.name = name or getattr(kernel, "__name__", "kernel").lstrip("_")
     self.mesh = mesh if mesh is not None else make_mesh()
     self.axis = self.mesh.axis_names[0]
-    self.cache_variant = cache_variant
+    # the program's module name is part of the variant: an executable
+    # stored under another name never loads under this one
+    self.cache_variant = (
+      None if cache_variant is None
+      else (cache_variant, "jit_" + program_name(self.name))
+    )
     self._cache = LRUCache()
     self._consts_cache = LRUCache()
 
@@ -184,8 +212,7 @@ class BatchKernelExecutor:
       batched, mesh=self.mesh,
       in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )
-    # lint: allow=IGN201 AOT lower+compile cached by signature at call site
-    return jax.jit(fn)
+    return _named_jit(fn, self.name)
 
   def __call__(self, batch, consts=None, span_attrs=None):
     """batch: pytree of (K, ...) arrays → pytree of (K, ...) numpy.
@@ -296,9 +323,10 @@ class ChunkExecutor:
     self._compiled = LRUCache()
     # persistent-cache key component: the pyramid configuration this
     # closure bakes in (name+signature alone cannot distinguish two
-    # factor chains of equal shapes)
+    # factor chains of equal shapes) and the program's module name
     self.cache_variant = (
-      "pyramid", self.factors, method, bool(sparse), self.planes
+      "pyramid", self.factors, method, bool(sparse), self.planes,
+      "jit_" + program_name(self.name),
     )
 
   def _build(self):
@@ -329,8 +357,7 @@ class ChunkExecutor:
     fn = jax.shard_map(
       per_shard, mesh=self.mesh, in_specs=(in_spec,), out_specs=out_spec
     )
-    # lint: allow=IGN201 AOT lower+compile cached by signature at call site
-    return jax.jit(fn)
+    return _named_jit(fn, self.name)
 
   @property
   def n_devices(self) -> int:

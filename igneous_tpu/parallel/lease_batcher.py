@@ -351,29 +351,29 @@ class LeaseBatcher:
         # the budget shrank between prefetch and now: surplus goes back
         self._release_members(members[cap:])
         members = members[:cap]
-      lease_t0 = time.time()
       synced = []
-      while len(members) < cap and not self._draining():
-        got = self._lease_many(cap - len(members))
-        if not got:
-          break
-        for leased in got:
-          members.append(leased)
-          self._hb.track(leased[1])
-          synced.append(leased[1])
-      if synced:
-        # per-round queue-interaction cost: the workload miner folds
-        # these into the round-overhead distribution the fleet
-        # simulator replays, so batched campaigns simulate queue time,
-        # not just compute. range_sizes (when present) records the
-        # round's contiguous-range composition for range-lease replay.
-        attrs = {"members": len(synced)}
-        sizes = _range_sizes(synced)
-        if sizes:
-          attrs["range_sizes"] = sizes
-        trace.record_root(
-          "lease.acquire", lease_t0, time.time() - lease_t0, **attrs,
-        )
+      acquire = trace.span("lease.acquire", worker=True)
+      with acquire:
+        while len(members) < cap and not self._draining():
+          got = self._lease_many(cap - len(members))
+          if not got:
+            break
+          for leased in got:
+            members.append(leased)
+            self._hb.track(leased[1])
+            synced.append(leased[1])
+        if synced:
+          # per-round queue-interaction cost: the workload miner folds
+          # these into the round-overhead distribution the fleet
+          # simulator replays, so batched campaigns simulate queue time,
+          # not just compute. range_sizes (when present) records the
+          # round's contiguous-range composition for range-lease replay.
+          acquire.set(members=len(synced))
+          sizes = _range_sizes(synced)
+          if sizes:
+            acquire.set(range_sizes=sizes)
+        else:
+          acquire.drop()  # an empty poll is no round's overhead
       if self._draining():
         # preempted between lease and dispatch: nothing ran, so every
         # member goes straight back (_release_members untracks each
@@ -492,7 +492,8 @@ class LeaseBatcher:
     fut, self._next_round = self._next_round, None
     if fut is None:
       return []
-    return fut.result()
+    with trace.span("lease.prefetch_wait"):
+      return fut.result()
 
   def _surrender_prefetch(self):
     """Drain/stop path: pre-leased members of a round that will never
@@ -561,6 +562,10 @@ class LeaseBatcher:
     cache leftovers matching it are dropped. Download failures are
     dropped silently — the round's own download retries and surfaces the
     real error."""
+    with trace.span("lease.prefetch"):
+      return self._prelease_and_prefetch_inner(cap, busy_writes)
+
+  def _prelease_and_prefetch_inner(self, cap, busy_writes):
     members = []
     while len(members) < cap and not self._draining():
       got = self._lease_many(cap - len(members))
@@ -642,19 +647,21 @@ class LeaseBatcher:
       self._hb.start()
     for _task, lease_id in members:
       self._hb.track(lease_id)  # idempotent for pre-leased members
-    t0 = time.time()
     before_exec = self.stats["executed"]
     before_fail = self.stats["failed"]
+    # worker-scoped span: one lease round (group dispatch + member
+    # completions) under the process's own trace id
+    round_span = trace.span("lease.round", worker=True, members=len(members))
     try:
-      self._run_round_inner(members)
+      with round_span:
+        try:
+          self._run_round_inner(members)
+        finally:
+          round_span.set(
+            executed=self.stats["executed"] - before_exec,
+            failed=self.stats["failed"] - before_fail,
+          )
     finally:
-      # worker-scoped span: one lease round (group dispatch + member
-      # completions) under the process's own trace id
-      trace.record_root(
-        "lease.round", t0, time.time() - t0, members=len(members),
-        executed=self.stats["executed"] - before_exec,
-        failed=self.stats["failed"] - before_fail,
-      )
       # cutouts this round's writes made stale must never feed a later
       # round from the prefetch cache (a member re-leased after failure,
       # say, whose cutout lingered unconsumed)
@@ -772,20 +779,22 @@ class LeaseBatcher:
   def _finish_members(self, group, finish_one):
     """Run each member's host completion; a failure keeps that member's
     lease only."""
-    for idx, (task, lease_id) in enumerate(group):
-      try:
-        # the member's completion span: its share of the batched round
-        # (the shared device dispatch is the round's own lease.round span)
-        with trace.task_span(
-          task, attempt=self._attempt_of(lease_id), mode="batched"
-        ):
-          run_with_deadline(
-            lambda: finish_one(idx, task), self.task_deadline_seconds
-          )
-      except Exception as e:
-        self._record_failure(lease_id, e)
-        continue
-      self._complete(lease_id)
+    with trace.span("lease.round.finish", members=len(group)):
+      for idx, (task, lease_id) in enumerate(group):
+        try:
+          # the member's completion span: its share of the batched round
+          # (the shared device dispatch is the round's own lease.round
+          # span)
+          with trace.task_span(
+            task, attempt=self._attempt_of(lease_id), mode="batched"
+          ):
+            run_with_deadline(
+              lambda: finish_one(idx, task), self.task_deadline_seconds
+            )
+        except Exception as e:
+          self._record_failure(lease_id, e)
+          continue
+        self._complete(lease_id)
 
   # -- group handlers -------------------------------------------------------
 
@@ -841,50 +850,52 @@ class LeaseBatcher:
 
     from ..pipeline import shared_prefetch_pool
 
-    imgs = list(shared_prefetch_pool().map(
-      fetch, list(enumerate(t for t, _ in group))
-    ))
+    with trace.span("lease.round.fetch", members=len(group)):
+      imgs = list(shared_prefetch_pool().map(
+        fetch, list(enumerate(t for t, _ in group))
+      ))
     mesh = self.mesh if self.mesh is not None else make_mesh()
 
     mips_out = None
     full_pos = {k: j for j, k in enumerate(full_idx)}
-    if full_idx:
-      is_u64 = method == "mode" and dest.dtype.itemsize == 8
-      executor = cached_chunk_executor(
-        mesh, factors=tuple(factors), method=method, sparse=t0.sparse,
-        planes=2 if is_u64 else 1,
-      )
-      mips_out = device_pyramid_batch(
-        executor, [imgs[k] for k in full_idx], is_u64
-      )
-      self.stats["dispatches"]["downsample"] += 1
-
     pyramid = None
     ragged_pos = {k: j for j, k in enumerate(ragged_idx)}
     released = set()
-    if ragged_idx:
-      from .paged import PagedPyramid
+    with trace.span("lease.round.dispatch", members=len(group)):
+      if full_idx:
+        is_u64 = method == "mode" and dest.dtype.itemsize == 8
+        executor = cached_chunk_executor(
+          mesh, factors=tuple(factors), method=method, sparse=t0.sparse,
+          planes=2 if is_u64 else 1,
+        )
+        mips_out = device_pyramid_batch(
+          executor, [imgs[k] for k in full_idx], is_u64
+        )
+        self.stats["dispatches"]["downsample"] += 1
 
-      pyramid = PagedPyramid(
-        [imgs[k] for k in ragged_idx], tuple(factors), len(factors),
-        method=method, sparse=t0.sparse, mesh=mesh,
-      )
-      while pyramid.pending:
-        if self._straggler_flagged():
-          shed = pyramid.split_unstarted()
-          if shed:
-            self._release_members([group[ragged_idx[j]] for j in shed])
-            self.stats["paged_splits"] += len(shed)
-            for j in shed:
-              k = ragged_idx[j]
-              released.add(k)
-              # the lease is back in the queue for a healthy worker: the
-              # group-fallback path must not ALSO rerun it solo here
-              self._completed_in_group.add(group[k][1])
-        if not pyramid.pending:
-          break
-        pyramid.run_round()
-        self.stats["dispatches"]["downsample_paged"] += 1
+      if ragged_idx:
+        from .paged import PagedPyramid
+
+        pyramid = PagedPyramid(
+          [imgs[k] for k in ragged_idx], tuple(factors), len(factors),
+          method=method, sparse=t0.sparse, mesh=mesh,
+        )
+        while pyramid.pending:
+          if self._straggler_flagged():
+            shed = pyramid.split_unstarted()
+            if shed:
+              self._release_members([group[ragged_idx[j]] for j in shed])
+              self.stats["paged_splits"] += len(shed)
+              for j in shed:
+                k = ragged_idx[j]
+                released.add(k)
+                # the lease is back in the queue for a healthy worker: the
+                # group-fallback path must not ALSO rerun it solo here
+                self._completed_in_group.add(group[k][1])
+          if not pyramid.pending:
+            break
+          pyramid.run_round()
+          self.stats["dispatches"]["downsample_paged"] += 1
 
     to_finish = [m for k, m in enumerate(group) if k not in released]
     idx_map = [k for k in range(len(group)) if k not in released]
@@ -935,27 +946,30 @@ class LeaseBatcher:
 
     from ..pipeline import shared_prefetch_pool
 
-    preps = list(shared_prefetch_pool().map(prep, [t for t, _ in group]))
+    with trace.span("lease.round.fetch", members=len(group)):
+      preps = list(shared_prefetch_pool().map(prep, [t for t, _ in group]))
 
     live = [i for i, p in enumerate(preps) if p is not None]
     fields = {}
-    if live and _host_backend() == "device":
-      # device hosts group ragged shapes under one key (ISSUE 12): the
-      # paged EDT relabels every member into one canonical-shape page
-      # batch, so the whole group rides a single compiled signature
-      from .paged import paged_edt
+    with trace.span("lease.round.dispatch", members=len(group)):
+      if live and _host_backend() == "device":
+        # device hosts group ragged shapes under one key: the
+        # paged EDT relabels every member into one canonical-shape page
+        # batch, so the whole group rides a single compiled signature
+        from .paged import paged_edt
 
-      edts = paged_edt([preps[i][0] for i in live], anis, mesh=self.mesh)
-      self.stats["dispatches"]["skeleton"] += 1
-      fields = {i: f for i, f in zip(live, edts)}
-    elif live:
-      labels_batch = np.stack([preps[i][0] for i in live])
-      # host backend: shapes partition the group key, so the stack is
-      # rectangular; no executor pin — edt_batch's host fallback keeps
-      # batched EDTs bit-identical to solo on accelerator-less hosts
-      edts = edt_batch(labels_batch, anis, black_border=True, executor=None)
-      self.stats["dispatches"]["skeleton"] += 1
-      fields = {i: f for i, f in zip(live, edts)}
+        edts = paged_edt([preps[i][0] for i in live], anis, mesh=self.mesh)
+        self.stats["dispatches"]["skeleton"] += 1
+        fields = {i: f for i, f in zip(live, edts)}
+      elif live:
+        labels_batch = np.stack([preps[i][0] for i in live])
+        # host backend: shapes partition the group key, so the stack is
+        # rectangular; no executor pin — edt_batch's host fallback keeps
+        # batched EDTs bit-identical to solo on accelerator-less hosts
+        edts = edt_batch(labels_batch, anis, black_border=True,
+                         executor=None)
+        self.stats["dispatches"]["skeleton"] += 1
+        fields = {i: f for i, f in zip(live, edts)}
 
     def finish(k, task):
       if preps[k] is None:
@@ -989,22 +1003,24 @@ class LeaseBatcher:
 
     from ..pipeline import shared_prefetch_pool
 
-    preps = list(shared_prefetch_pool().map(prep, [t for t, _ in group]))
+    with trace.span("lease.round.fetch", members=len(group)):
+      preps = list(shared_prefetch_pool().map(prep, [t for t, _ in group]))
 
     from .paged import ccl_page_compatible
 
-    if ccl_page_compatible():
-      # page-compatible tile: ragged cutouts share the group key
-      # (ISSUE 12), and the paged CCL runs them all through one
-      # fixed-page-batch signature
-      from .paged import paged_ccl
+    with trace.span("lease.round.dispatch", members=len(group)):
+      if ccl_page_compatible():
+        # page-compatible tile: ragged cutouts share the group key,
+        # and the paged CCL runs them all through one
+        # fixed-page-batch signature
+        from .paged import paged_ccl
 
-      comps = paged_ccl([p[0] for p in preps], 6, mesh=self.mesh)
-    else:
-      imgs = np.stack([p[0] for p in preps])
-      comps = connected_components_batch(
-        imgs, executor=_batch_executor(6, mesh=self.mesh)
-      )
+        comps = paged_ccl([p[0] for p in preps], 6, mesh=self.mesh)
+      else:
+        imgs = np.stack([p[0] for p in preps])
+        comps = connected_components_batch(
+          imgs, executor=_batch_executor(6, mesh=self.mesh)
+        )
     self.stats["dispatches"]["ccl_faces"] += 1
 
     def finish(k, task):
@@ -1020,9 +1036,10 @@ class LeaseBatcher:
     of per task); emit/weld/simplify/upload stay per task."""
     from ..tasks.mesh import execute_mesh_tasks_batched
 
-    dispatches = execute_mesh_tasks_batched(
-      [t for t, _ in group], mesh=self.mesh,
-    )
+    with trace.span("lease.round.dispatch", members=len(group)):
+      dispatches = execute_mesh_tasks_batched(
+        [t for t, _ in group], mesh=self.mesh,
+      )
     self.stats["dispatches"]["mesh"] += dispatches
 
     def finish(k, task):

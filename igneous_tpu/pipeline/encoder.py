@@ -44,24 +44,24 @@ class UploadTicket:
     self._futures: List[cf.Future] = []  # guarded-by: self._lock
 
   def submit(self, fn: Callable[[], None]) -> None:
-    # carry the submitting thread's trace context onto the pool thread:
-    # the chunk's encode+put work (and its storage spans) stays
-    # attributed to the task that produced it
+    # every chunk's encode+put is timed, sampled or not; a sampled trace
+    # context is carried onto the pool thread, so the work (and its
+    # storage spans) stays attributed to the task that produced it
     ctx = trace.current()
-    if ctx is not None and ctx.sampled:
-      inner = fn
+    if ctx is not None and not ctx.sampled:
+      ctx = None
 
-      def fn():
-        with trace.activate(ctx):
-          t0 = time.perf_counter()
-          try:
-            inner()
-          finally:
-            telemetry.observe(
-              "pipeline.encode_upload.s", time.perf_counter() - t0
-            )
+    def timed():
+      with trace.activate(ctx):
+        t0 = time.perf_counter()
+        try:
+          fn()
+        finally:
+          telemetry.observe(
+            "pipeline.encode_upload.s", time.perf_counter() - t0
+          )
 
-    fut = self._pool._submit(fn)
+    fut = self._pool._submit(timed)
     with self._lock:
       self._futures.append(fut)
 
@@ -72,12 +72,13 @@ class UploadTicket:
     with self._lock:
       futures, self._futures = self._futures, []
     first_error = None
-    for fut in futures:
-      try:
-        fut.result()
-      except BaseException as e:  # noqa: BLE001 - re-raised below
-        if first_error is None:
-          first_error = e
+    with trace.span("pipeline.encode.join", chunks=len(futures)):
+      for fut in futures:
+        try:
+          fut.result()
+        except BaseException as e:  # noqa: BLE001 - re-raised below
+          if first_error is None:
+            first_error = e
     if first_error is not None:
       raise first_error
 

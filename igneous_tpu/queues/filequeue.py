@@ -69,6 +69,7 @@ import time
 import uuid
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from ..observability import trace
 from .ranges import RangeLease, RangeSub
 from .registry import RegisteredTask, deserialize, serialize
 
@@ -213,7 +214,6 @@ def poll_loop(
   task finishes, no new lease is taken."""
   from .. import telemetry
   from ..observability import journal as journal_mod
-  from ..observability import trace
   from .heartbeat import LeaseHeartbeat
 
   def draining() -> bool:
@@ -1031,6 +1031,10 @@ class FileQueue:
     range members — (task, :class:`RangeSub`) pairs sharing one
     underlying lease — classic files as (task, lease_id) pairs; the two
     mix freely in one result. Returns [] when the queue is drained."""
+    with trace.span("queue.lease"):
+      return self._lease_batch(seconds, max_tasks)
+
+  def _lease_batch(self, seconds, max_tasks):
     self._recycle_expired()
     out: List[Tuple[RegisteredTask, object]] = []
     refreshed = False
@@ -1077,6 +1081,10 @@ class FileQueue:
     Zombie fencing: renewal is refused (StaleLeaseError + ``zombie.renew``
     counter) once the lease has expired or the task was re-issued — a
     stalled worker that wakes up cannot re-acquire what it lost."""
+    with trace.span("queue.renew"):
+      return self._renew(lease_id, seconds)
+
+  def _renew(self, lease_id, seconds):
     from .. import telemetry
 
     if isinstance(lease_id, RangeSub):
@@ -1109,6 +1117,10 @@ class FileQueue:
     False + a ``zombie.delete`` counter instead of double-completing
     (the acceptance invariant: completions tally == task count). A range
     member acks its sub-range: the parent lease shrinks by one index."""
+    with trace.span("queue.delete"):
+      return self._delete(lease_id)
+
+  def _delete(self, lease_id):
     from .. import telemetry
 
     if isinstance(lease_id, RangeSub):
@@ -1149,6 +1161,10 @@ class FileQueue:
     A nack whose lease was already re-issued (or completed) is dropped
     with a ``zombie.nack`` counter — recording it would resurrect meta
     for a task this worker no longer owns."""
+    with trace.span("queue.nack"):
+      return self._nack(lease_id, reason, requeue)
+
+  def _nack(self, lease_id, reason, requeue):
     if isinstance(lease_id, RangeSub):
       return self._range_nack(
         lease_id.parent, lease_id.index, reason, requeue=requeue
@@ -1170,6 +1186,10 @@ class FileQueue:
   def release(self, lease_id):
     """Return a lease to rotation immediately (undelivered). A range
     member releases just its index back as a fresh one-task segment."""
+    with trace.span("queue.release"):
+      return self._release(lease_id)
+
+  def _release(self, lease_id):
     if isinstance(lease_id, RangeSub):
       return self._range_release(lease_id.parent, [lease_id.index])
     orig = lease_id.split(LEASE_SEP, 1)[1]

@@ -17,7 +17,6 @@ from .observability.metrics import (  # noqa: F401
   StageTimes,
   _stack,
   counters_snapshot,
-  device_trace,
   emit_counters,
   gauge_max,
   gauge_set,
@@ -37,7 +36,7 @@ from .observability.metrics import (  # noqa: F401
 )
 
 __all__ = [
-  "StageTimes", "counters_snapshot", "device_trace", "emit_counters",
+  "StageTimes", "counters_snapshot", "emit_counters",
   "gauge_max", "gauge_set", "gauges_snapshot", "histograms_snapshot",
   "incr", "observe", "observe_quiet",
   "queue_eta", "reset_all", "reset_counters", "stage", "task_timing",

@@ -22,6 +22,7 @@ import numpy as np
 from . import chunk_cache, codecs, integrity, telemetry
 from .lib import Bbox, Vec, chunk_bboxes, jsonify
 from .meta import PrecomputedMetadata
+from .observability import trace as _trace
 from .storage import CloudFiles, decompress_bytes
 
 IO_THREADS = 8
@@ -337,6 +338,12 @@ class Volume:
       )
     mip = self.mip if mip is None else mip
     bbox = Bbox(bbox.minpt, bbox.maxpt)
+    with _trace.span("volume.download", voxels=int(np.prod(bbox.size3()))):
+      return self._download(bbox, mip, renumber, label, parallel,
+                            agglomerate, timestamp, stop_layer)
+
+  def _download(self, bbox, mip, renumber, label, parallel, agglomerate,
+                timestamp, stop_layer):
     bounds = self.meta.bounds(mip)
     if self.bounded:
       if not bounds.contains_bbox(bbox):
@@ -365,41 +372,23 @@ class Volume:
         if not c.empty()
       ]
       keys = [self.meta.chunk_name(mip, c) for c in chunks]
-      stored = self._parallel_get_stored(keys, parallel)
+      with _trace.span("volume.get", chunks=len(keys)):
+        stored = self._parallel_get_stored(keys, parallel)
       # read-only decode (possibly straight from the shared decode
       # cache): the voxels are copied into the assembly buffer below, so
       # a writable defensive copy here would be pure overhead
-      renders = [
-        (c, self._decode_stored(s, c, mip))
-        for c, s in zip(chunks, stored)
-      ]
+      with _trace.span("volume.decode", chunks=len(keys)):
+        renders = [
+          (c, self._decode_stored(s, c, mip))
+          for c, s in zip(chunks, stored)
+        ]
 
     # Fortran order end to end: decoded chunks are F-order views, the
     # device layout (c,z,y,x) is a zero-copy transpose of an F-order
     # cutout, and raw encode is tobytes("F") — C-order assembly here would
     # force a full-volume transpose copy on BOTH sides of the compute.
-    out_shape = tuple(int(v) for v in bbox.size3()) + (self.num_channels,)
-    if inner == bbox:
-      # the chunk grid covers every output voxel (missing chunks arrive
-      # background-filled): skip the background memset
-      out = np.empty(out_shape, dtype=self.dtype, order="F")
-    else:
-      out = np.full(
-        out_shape, self.background_color, dtype=self.dtype, order="F"
-      )
-    for chunk_bbx, chunk_img in renders:
-      isect = Bbox.intersection(chunk_bbx, bbox)
-      if isect.empty():
-        continue
-      dst = tuple(
-        slice(int(a), int(b))
-        for a, b in zip(isect.minpt - bbox.minpt, isect.maxpt - bbox.minpt)
-      )
-      src = tuple(
-        slice(int(a), int(b))
-        for a, b in zip(isect.minpt - chunk_bbx.minpt, isect.maxpt - chunk_bbx.minpt)
-      )
-      out[dst] = chunk_img[src]
+    with _trace.span("volume.assemble"):
+      out = self._assemble(bbox, inner, renders)
 
     if self.graphene is not None and (agglomerate or stop_layer is not None):
       from .graphene import voxel_chunk_index
@@ -428,6 +417,31 @@ class Volume:
     if renumber:
       out, mapping = _renumber(out)
       return out, mapping
+    return out
+
+  def _assemble(self, bbox: Bbox, inner: Bbox, renders) -> np.ndarray:
+    out_shape = tuple(int(v) for v in bbox.size3()) + (self.num_channels,)
+    if inner == bbox:
+      # the chunk grid covers every output voxel (missing chunks arrive
+      # background-filled): skip the background memset
+      out = np.empty(out_shape, dtype=self.dtype, order="F")
+    else:
+      out = np.full(
+        out_shape, self.background_color, dtype=self.dtype, order="F"
+      )
+    for chunk_bbx, chunk_img in renders:
+      isect = Bbox.intersection(chunk_bbx, bbox)
+      if isect.empty():
+        continue
+      dst = tuple(
+        slice(int(a), int(b))
+        for a, b in zip(isect.minpt - bbox.minpt, isect.maxpt - bbox.minpt)
+      )
+      src = tuple(
+        slice(int(a), int(b))
+        for a, b in zip(isect.minpt - chunk_bbx.minpt, isect.maxpt - chunk_bbx.minpt)
+      )
+      out[dst] = chunk_img[src]
     return out
 
   def _parallel_get_stored(self, keys: List[str], parallel: Optional[int]):

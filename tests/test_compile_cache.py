@@ -86,7 +86,7 @@ def test_version_skew_reads_as_miss(cache_root):
   assert device_mod.LEDGER.compile_cache["puts"] == 1
   skewed = cc.entry_meta(
     "test.double", next(iter(ex._cache.keys())), mesh=mesh,
-    variant=("test_double",),
+    variant=ex.cache_variant,
   )
   skewed["jax"] = "999.0.0"
   assert cache.get(skewed) is None
@@ -105,7 +105,7 @@ def _seed_entry(cache_root):
   out = ex(np.arange(8, dtype=np.float32).reshape(2, 4))
   sig = next(iter(ex._cache.keys()))
   meta = cc.entry_meta(
-    "test.double", sig, mesh=mesh, variant=("test_double",)
+    "test.double", sig, mesh=mesh, variant=ex.cache_variant
   )
   key = cc.entry_key(meta)
   path = os.path.join(cache_root[len("file://"):], key)

@@ -8,11 +8,9 @@ Satellite coverage checklist:
   * the flags-file profiler trigger round-trips (request → worker poll →
     capture → artifacts + journal marker, exactly once);
   * `igneous fleet devices` merges per-worker journal ledgers;
-plus the health-engine device anomalies and the hardened device_trace
-context manager.
+plus the health-engine device anomalies.
 """
 
-import os
 import time
 
 import numpy as np
@@ -26,7 +24,6 @@ from igneous_tpu.observability import (
   fleet,
   health,
   journal as journal_mod,
-  metrics,
   perfetto,
   prom,
   rollup,
@@ -438,24 +435,3 @@ def test_stale_profile_request_ignored(tmp_path):
   assert device_mod.read_profile_request(jpath) is None
 
 
-def test_device_trace_inert_without_env(monkeypatch):
-  monkeypatch.delenv("IGNEOUS_PROFILE_DIR", raising=False)
-  monkeypatch.delenv("IGNEOUS_TPU_PROFILE_DIR", raising=False)
-  with metrics.device_trace():
-    pass  # must not import jax / start anything
-
-
-def test_device_trace_namespaced_and_exception_safe(tmp_path, monkeypatch):
-  monkeypatch.setenv("IGNEOUS_PROFILE_DIR", str(tmp_path))
-  with pytest.raises(RuntimeError):
-    with metrics.device_trace():
-      import jax.numpy as jnp
-
-      jnp.ones((8, 8)).sum().block_until_ready()
-      raise RuntimeError("region failure")
-  # stop_trace ran despite the exception: a fresh trace can start, and
-  # the logdir is namespaced per worker process (hostname-pid)
-  with metrics.device_trace():
-    pass
-  entries = os.listdir(tmp_path)
-  assert entries and any(str(os.getpid()) in e for e in entries)
