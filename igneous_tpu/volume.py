@@ -377,11 +377,11 @@ class Volume:
       # read-only decode (possibly straight from the shared decode
       # cache): the voxels are copied into the assembly buffer below, so
       # a writable defensive copy here would be pure overhead
-      with _trace.span("volume.decode", chunks=len(keys)):
-        renders = [
-          (c, self._decode_stored(s, c, mip))
-          for c, s in zip(chunks, stored)
-        ]
+      workers = self._decode_workers(stored, mip, parallel)
+      with _trace.span("volume.decode", chunks=len(keys), workers=workers):
+        renders = list(zip(
+          chunks, self._decode_all(chunks, stored, mip, workers)
+        ))
 
     # Fortran order end to end: decoded chunks are F-order views, the
     # device layout (c,z,y,x) is a zero-copy transpose of an F-order
@@ -456,6 +456,37 @@ class Volume:
     from .pipeline.encoder import shared_io_pool
 
     return list(shared_io_pool().map(self.cf.get_stored, keys))
+
+  def _decode_workers(self, stored, mip: int, parallel: Optional[int]) -> int:
+    """How many of a cutout's chunks decode at once: the shared IO pool's
+    width when more than one chunk needs inflate or codec work, else 1.
+    Uncompressed raw chunks decode as zero-copy views, which a pool hop
+    would only slow down; parallel=1 keeps strict serial semantics."""
+    if (parallel or IO_THREADS) <= 1:
+      return 1
+    raw = self.meta.encoding(mip) == "raw"
+    work = sum(
+      1 for data, method in stored
+      if data is not None and (method is not None or not raw)
+    )
+    if work <= 1:
+      return 1
+    from .pipeline import config
+
+    return min(config.io_threads(), len(stored))
+
+  def _decode_all(self, chunks, stored, mip: int, workers: int):
+    """Decoded chunks in chunk order. PIL's decoders, zlib and the cseg
+    decoders release the GIL, so ``workers`` > 1 scales with the host's
+    cores. No caller downloads inside the IO pool, so its workers never
+    wait on the pool they run on."""
+    if workers <= 1:
+      return [self._decode_stored(s, c, mip) for c, s in zip(chunks, stored)]
+    from .pipeline.encoder import shared_io_pool
+
+    return list(shared_io_pool().map(
+      lambda cs: self._decode_stored(cs[1], cs[0], mip), zip(chunks, stored)
+    ))
 
   def __getitem__(self, slices) -> np.ndarray:
     bbox = self._interpret_slices(slices)

@@ -127,12 +127,18 @@ def test_volume_download_holds_get_and_decode(rounds, mode):
   events = rounds[mode][0]
   downloads = _named(events, "volume.download")
   assert len(downloads) == 2
+  # one decode span per download, on the downloading thread, however
+  # many pool threads decode its chunks
+  assert len(_named(events, "volume.decode")) == len(downloads)
   for dl in downloads:
     assert dl[4]["voxels"] == TASK_VOXELS
-    parts = [e[1] for e in sorted(events, key=lambda e: e[2])
+    parts = [e for e in sorted(events, key=lambda e: e[2])
              if e[1].startswith("volume.") and e is not dl
              and _inside(e, dl)]
-    assert parts == ["volume.get", "volume.decode", "volume.assemble"]
+    assert [e[1] for e in parts] == [
+      "volume.get", "volume.decode", "volume.assemble"]
+    # four gzip chunks a cutout: the decode fans out
+    assert parts[1][4]["chunks"] == 4 and parts[1][4]["workers"] > 1
 
 
 def test_device_execute_carries_kernel(rounds):
