@@ -32,8 +32,8 @@ PASS_ID = "telemetry"
 SUBSYSTEMS = frozenset({
   "autoscale", "campaign", "chaos", "chunk_cache", "device", "dlq",
   "drain", "fleet", "health", "infer", "integrity", "journal", "lease",
-  "metrics", "pipeline", "queue", "retries", "rollup", "serve", "sim",
-  "slo", "speculation", "steal", "storage", "tasks", "transfer",
+  "metrics", "pipeline", "pooling", "queue", "retries", "rollup", "serve",
+  "sim", "slo", "speculation", "steal", "storage", "tasks", "transfer",
   "volume", "worker", "zombie",
 })
 

@@ -11,6 +11,9 @@ Design notes (TPU):
   - One jitted program produces the whole mip pyramid: each mip is a
     reshape-into-windows + reduce, which XLA fuses into tight VPU loops —
     no HBM round-trips between mips.
+  - The batched executor walks a cutout in z-slabs that no window
+    crosses (``slab_depth``), so the window temporaries are one slab's:
+    the 2048x2048x64 uint64 task fits one v5e two at a time.
   - Mode pooling counts pairwise equality over the (≤8-voxel) window and
     argmaxes a score that encodes "highest count, ties to the earliest
     window position (z-major, then y, then x)". Equality-only compares mean
@@ -205,12 +208,113 @@ def _pool_once(x, f: Factor3, method: str, sparse: bool):
   raise ValueError(f"Unknown downsample method: {method}")
 
 
-def _pyramid_impl(x, factors: Tuple[Factor3, ...], method: str, sparse: bool):
+def _pyramid_whole(x, factors: Tuple[Factor3, ...], method: str, sparse: bool):
   outs = []
   for f in factors:
     x = _pool_once(x, f, method, sparse)
     outs.append(x)
   return tuple(outs)
+
+
+def _pyramid_impl(x, factors: Tuple[Factor3, ...], method: str, sparse: bool,
+                  depth: Optional[int] = None):
+  """Every mip of ``x`` ((c, z, y, x), or a tuple of such planes for
+  mode), walked in z-slabs ``depth`` deep (see :func:`slab_depth`).
+
+  No window crosses a slab whose depth is a multiple of the product of
+  the z factors, so each slab's pyramid is written into preallocated
+  per-mip buffers at its z offset: the temporaries of the window slices
+  and equality maps are then one slab's, not the whole cutout's. A depth
+  of None or of the whole z extent is one slab, the whole-cutout program.
+  """
+  planes = x if isinstance(x, tuple) else (x,)
+  z = planes[0].shape[1]
+  if depth is None or depth >= z:
+    return _pyramid_whole(x, factors, method, sparse)
+  step = _z_step(factors)
+  if depth % step or z % depth:
+    raise ValueError(f"slab depth {depth} must be a multiple of {step} "
+                     f"that divides z extent {z}")
+
+  def slab(i):
+    s = tuple(jax.lax.dynamic_slice_in_dim(p, i * depth, depth, axis=1)
+              for p in planes)
+    return s if isinstance(x, tuple) else s[0]
+
+  n = z // depth
+  shapes = jax.eval_shape(
+    lambda s: _pyramid_whole(s, factors, method, sparse), slab(0))
+  bufs = jax.tree.map(
+    lambda s: jnp.zeros((s.shape[0], s.shape[1] * n) + s.shape[2:], s.dtype),
+    shapes)
+
+  def body(i, bufs):
+    outs = _pyramid_whole(slab(i), factors, method, sparse)
+    return jax.tree.map(
+      lambda b, o: jax.lax.dynamic_update_slice_in_dim(
+        b, o, i * o.shape[1], axis=1),
+      bufs, outs)
+
+  return jax.lax.fori_loop(0, n, body, bufs)
+
+
+def _z_step(factors: Sequence[Factor3]) -> int:
+  """The depth no window of the chain crosses: the product of the z
+  factors."""
+  return int(np.prod([int(f[2]) for f in factors]))
+
+
+# Temporaries of a pyramid pass, as a multiple of the input it pools at
+# once: the u8 average sums each window position in int32 (5.75x its input,
+# the most of any method, compiled for a v5e), mode holds the pairwise
+# equality maps (3x).
+TEMP_PER_INPUT = 6
+
+# Published HBM per chip, for a device that cannot report its memory (one
+# described for an ahead-of-time compile, which is not addressable).
+_HBM_BYTES = {"TPU v5 lite": 16 * 2**30}
+
+
+def device_memory_bytes(device) -> Optional[int]:
+  """The memory a pyramid may plan against on ``device``: its allocator's
+  limit where it reports one, else its kind's published HBM; None for a
+  host backend, which reports neither."""
+  try:
+    stats = device.memory_stats() or {}
+  except jax.errors.JaxRuntimeError:  # described, not addressable
+    stats = {}
+  limit = stats.get("bytes_limit")
+  return int(limit) if limit else _HBM_BYTES.get(device.device_kind)
+
+
+def walk_bytes(z: int, depth: int, factors: Sequence[Factor3],
+               plane_bytes: int) -> int:
+  """Device bytes a pyramid walked in slabs ``depth`` deep needs: the whole
+  input (``plane_bytes`` a z plane, over everything one dispatch pools)
+  and every mip, plus one slab's temporaries."""
+  mips, vol = 0.0, 1
+  for f in factors:
+    vol *= int(f[0]) * int(f[1]) * int(f[2])
+    mips += 1 / vol
+  return int(z * plane_bytes * (1 + mips)
+             + TEMP_PER_INPUT * depth * plane_bytes)
+
+
+def slab_depth(z: int, factors: Sequence[Factor3], plane_bytes: int,
+               memory_bytes: Optional[int]) -> int:
+  """The z-slab depth the pyramid walks: the deepest multiple of the z
+  factors' product that divides ``z`` and whose walk fits
+  ``memory_bytes`` (:func:`walk_bytes`); at least that product. The
+  whole extent, one slab, when that fits, when memory is unknown, or when
+  ``z`` is no multiple of the product (the edge padding in z then needs
+  the whole cutout). Fewer, deeper slabs run faster on the chip: each
+  slab's window slices are gathers whose rows hold its z planes."""
+  step = _z_step(factors)
+  if memory_bytes is None or z % step:
+    return z
+  fits = [d for d in range(step, z + 1, step) if z % d == 0
+          and walk_bytes(z, d, factors, plane_bytes) <= memory_bytes]
+  return max(fits, default=step)
 
 
 _jit_pyramid = partial(

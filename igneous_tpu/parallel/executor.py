@@ -22,7 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import compile_cache
 from ..analysis import knobs
 from ..observability import device as device_telemetry
-from ..ops.pooling import _pyramid_impl
+from ..ops.pooling import _pyramid_impl, device_memory_bytes, slab_depth
 
 
 
@@ -329,15 +329,36 @@ class ChunkExecutor:
       "jit_" + program_name(self.name),
     )
 
+  def slab_depth(self, shard_shape, dtypes) -> int:
+    """The z-slab depth the pyramid walks for one device's shard: planes
+    of ``shard_shape`` (k, c, z, y, x) in ``dtypes``
+    (``ops.pooling.slab_depth``). A z plane's bytes count every task and
+    plane of the shard, which one slab step pools at once."""
+    k, c, z, y, x = shard_shape
+    plane_bytes = k * c * y * x * sum(np.dtype(d).itemsize for d in dtypes)
+    return slab_depth(z, self.factors, plane_bytes,
+                      device_memory_bytes(self.mesh.devices.flat[0]))
+
+  def _walk_attrs(self, arrs) -> dict:
+    """``slabs`` and ``slab_depth`` of a dispatch of global ``arrs``, for
+    its device.execute span."""
+    k, *rest = arrs[0].shape
+    z = rest[1]
+    depth = self.slab_depth((k // self.n_devices, *rest),
+                            [a.dtype for a in arrs])
+    return {"slabs": z // depth, "slab_depth": depth}
+
   def _build(self):
     factors, method, sparse = self.factors, self.method, self.sparse
     axis = self.axis
     planes = self.planes
 
     def per_shard(xs):  # xs: tuple of (k, c, z, y, x) local shards
+      depth = self.slab_depth(xs[0].shape, [a.dtype for a in xs])
+
       def one(arrs):
         val = arrs if planes == 2 else arrs[0]
-        return _pyramid_impl(val, factors, method, sparse)
+        return _pyramid_impl(val, factors, method, sparse, depth)
 
       outs = jax.vmap(lambda *arrs: one(arrs))(*xs)
       # voxel count psum: a cross-chip collective over ICI so callers get
@@ -354,8 +375,12 @@ class ChunkExecutor:
     else:
       mip_spec = tuple(P(self.axis) for _ in factors)
     out_spec = (mip_spec, P())
+    # check_vma off: the slab walk's mip buffers start from literals, which
+    # the varying-manual-axes checker rejects as a loop carry (see
+    # BatchKernelExecutor._build)
     fn = jax.shard_map(
-      per_shard, mesh=self.mesh, in_specs=(in_spec,), out_specs=out_spec
+      per_shard, mesh=self.mesh, in_specs=(in_spec,), out_specs=out_spec,
+      check_vma=False,
     )
     return _named_jit(fn, self.name)
 
@@ -398,7 +423,7 @@ class ChunkExecutor:
         self._compiled[sig] = compiled
       with device_telemetry.execute_span(
         self.name, elements=device_telemetry.elements_of(arrs),
-        mesh=self.mesh, **self.span_attrs,
+        mesh=self.mesh, **self._walk_attrs(arrs), **self.span_attrs,
       ):
         out = compiled(tuple(arrs))
         jax.block_until_ready(out)
@@ -412,7 +437,7 @@ class ChunkExecutor:
       ) if fresh else
       device_telemetry.execute_span(
         self.name, elements=device_telemetry.elements_of(arrs),
-        mesh=self.mesh, **self.span_attrs,
+        mesh=self.mesh, **self._walk_attrs(arrs), **self.span_attrs,
       )
     )
     with span:
@@ -453,7 +478,7 @@ class ChunkExecutor:
     with device_telemetry.execute_span(
       self.name, elements=sum(int(p.size) for p in padded),
       nbytes=sum(int(p.nbytes) for p in padded), mesh=self.mesh,
-      **self.span_attrs,
+      **self._walk_attrs(xs), **self.span_attrs,
     ):
       outs, nonzero = self._compiled[sig](xs)
       jax.block_until_ready((outs, nonzero))

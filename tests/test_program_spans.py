@@ -152,6 +152,70 @@ def test_device_execute_carries_kernel(rounds):
   assert h2d and h2d[0][4]["bytes"] > 0 and h2d[0][4]["kernel"] == kernels[0]
 
 
+def test_average_pyramid_walks_one_slab_on_the_host(rounds):
+  """The host backend reports no memory: the whole cutout is one slab."""
+  (execute,) = _named(rounds["sampled"][0], "device.execute")
+  assert (execute[4]["slabs"], execute[4]["slab_depth"]) == (1, SHAPE[2])
+
+
+@pytest.fixture(scope="module")
+def u64_round(tmp_path_factory):
+  """One lease round of two uint64 segmentation tasks, with the device's
+  memory taken as small enough that the pyramid walks 4 slabs of 4."""
+  import jax
+
+  from igneous_tpu.ops import pooling
+  from igneous_tpu.parallel import executor as executor_mod
+
+  root = tmp_path_factory.mktemp("u64spans")
+  rng = np.random.default_rng(11)
+  ids = rng.integers(2**32, 2**48, 6, dtype=np.uint64)
+  data = ids[rng.integers(0, 6, (2 * SHAPE[0], SHAPE[1], SHAPE[2]))]
+  path = f"file://{root}/seg"
+  vol = Volume.from_numpy(data, path, chunk_size=(32, 32, 16),
+                          layer_type="segmentation")
+  create_downsample_scales(vol.meta, 0, SHAPE, (2, 2, 1), num_mips=1)
+  vol.commit_info()
+  q = FileQueue(f"fq://{root}/q")
+  q.insert_batch(_tasks(path))
+  plane_bytes = SHAPE[0] * SHAPE[1] * 8  # a task's (lo, hi) z plane a chip
+  with pytest.MonkeyPatch.context() as mp:
+    mp.setenv("IGNEOUS_POOL_HOST", "0")
+    memory = pooling.walk_bytes(SHAPE[2], 4, ((2, 2, 1),), plane_bytes)
+    mp.setattr(executor_mod, "device_memory_bytes", lambda device: memory)
+    mp.setattr(executor_mod, "_CHUNK_EXECUTOR_CACHE", {})
+    assert not device_mod._PROFILE_STATE["active"]
+    logdir = f"{root}/trace"
+    jax.profiler.start_trace(logdir)
+    try:
+      executed, stats = poll_batched(
+        q, batch_size=2, mesh=make_mesh(2),
+        stop_fn=lambda executed, empty: empty,
+      )
+    finally:
+      jax.profiler.stop_trace()
+  assert executed == 2 and stats["batched"] == 2
+  assert stats["group_fallbacks"] == 0
+  return _events(logdir)
+
+
+def test_u64_planes_split_and_pack_carry_bytes(u64_round):
+  (dispatch,) = _named(u64_round, "lease.round.dispatch")
+  (split,) = _named(u64_round, "pooling.u64_split")
+  (pack,) = _named(u64_round, "pooling.u64_pack")
+  (execute,) = _named(u64_round, "device.execute")
+  assert _inside(split, dispatch) and _inside(pack, dispatch)
+  assert split[3] <= execute[2] and execute[3] <= pack[2]
+  assert split[4]["bytes"] == 2 * TASK_VOXELS * 8  # two uint64 cutouts
+  assert pack[4]["bytes"] == 2 * TASK_VOXELS // 4 * 8  # their mip 1
+
+
+def test_device_execute_carries_slab_walk(u64_round):
+  (execute,) = _named(u64_round, "device.execute")
+  assert execute[4]["kernel"] == "pooling.pyramid[mode]"
+  assert (execute[4]["slabs"], execute[4]["slab_depth"]) == (4, 4)
+
+
 def test_sampling_off_keeps_annotations_and_journals_nothing(rounds):
   events, spans, _stats = rounds["unsampled"]
   assert spans == []

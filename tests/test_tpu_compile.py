@@ -84,6 +84,23 @@ def test_batched_chunk_pyramid(topo, chips, k, method):
     assert "all-reduce" in compiled.as_text()  # the nonzero psum
 
 
+def test_default_u64_task_batch_of_two_fits_one_chip(topo):
+  """The planner's default uint64 segmentation task (2048x2048x64, 4 mips
+  at 2,2,1) at --batch 2 on one chip: the pyramid walks z-slabs, so its
+  temporaries are one slab's and the program fits with room to spare."""
+  from igneous_tpu.parallel.executor import ChunkExecutor
+
+  mesh = Mesh(np.asarray(topo.devices[:1]), ("chunks",))
+  ex = ChunkExecutor(mesh, factors=FACTORS4, method="mode", planes=2)
+  plane = _spec((2, 1, 64, 2048, 2048), jnp.uint32,
+                NamedSharding(mesh, P("chunks")))
+  assert ex.slab_depth(plane.shape, [plane.dtype] * 2) < 64
+  mem = _compile(ex._fn, (plane, plane)).memory_analysis()
+  total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+           + mem.temp_size_in_bytes)
+  assert total < 12e9, total
+
+
 def test_paged_pyramid_default_page_batch(one_chip):
   from igneous_tpu.parallel.paged import (
     _make_page_kernel, page_round_cap, page_shape,
