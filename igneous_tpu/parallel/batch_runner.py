@@ -27,8 +27,10 @@ from ..downsample_scales import compute_factors, DEFAULT_FACTOR
 from ..task_creation.common import get_bounds
 from ..tasks.image import DownsampleTask
 from ..ops.pooling import (
+  _copy_by_planes,
   _from_device_layout,
   _pack_u64_planes,
+  _plane_runs,
   _split_u64_planes,
   _to_device_layout,
 )
@@ -39,40 +41,64 @@ _to_batch_layout = _to_device_layout
 _from_batch_layout = _from_device_layout
 
 
-def _u64_plane_batches(imgs):
-  """K same-shape uint64 (x,y,z[,c]) cutouts → (lo, hi) uint32 batches
-  (K, c, z, y, x). Each plane of each cutout is one strided copy from a
-  zero-copy view straight into its batch, and the 2K copies run side by
-  side: numpy lets go of the GIL while it copies, and one thread moves a
-  fraction of the host's memory bandwidth."""
-  views = [
-    p.transpose(3, 2, 1, 0)  # the (x,y,z,c) <-> (c,z,y,x) convention
-    for i in imgs
-    for p in _split_u64_planes(i if i.ndim == 4 else i[..., np.newaxis])
-  ]
-  lo, hi = (np.empty((len(imgs),) + views[0].shape, np.uint32)
-            for _ in range(2))
-  dsts = [b[k] for k in range(len(imgs)) for b in (lo, hi)]
-  with cf.ThreadPoolExecutor(len(dsts)) as pool:
-    list(pool.map(np.copyto, dsts, views))
-  return lo, hi
+def _batch_sources(imgs, is_u64_mode: bool):
+  """Zero-copy (c,z,y,x) views of K same-shape (x,y,z[,c]) cutouts, one
+  list per batch of a dispatch: the (lo, hi) uint32 planes of uint64
+  cutouts, else the cutouts themselves."""
+  def views(arrs):
+    return [(a if a.ndim == 4 else a[..., np.newaxis]).transpose(3, 2, 1, 0)
+            for a in arrs]
+
+  if is_u64_mode:
+    return [views(planes)
+            for planes in zip(*(_split_u64_planes(i) for i in imgs))]
+  return [views(imgs)]
+
+
+def _stack_into(batches, sources) -> int:
+  """Write the (K, c, z, y, x) ``batches``, member k of batch b from
+  ``sources[b][k]``, run by run of z-planes. A run writes every batch's
+  planes of its cutout, so the (lo, hi) copies of a uint64 cutout read
+  the source lines the first one just brought in. Returns the runs that
+  ran side by side."""
+  def stack(index):
+    for batch, views in zip(batches, sources):
+      batch[index] = views[index[0]][index[1:]]
+
+  return _copy_by_planes(stack, batches[0].shape,
+                         sum(int(b.nbytes) for b in batches))
 
 
 def device_pyramid_batch(executor, imgs, is_u64_mode: bool):
   """K same-shape (x,y,z[,c]) cutouts → per-mip batch arrays via ONE
-  ChunkExecutor dispatch. uint64 mode rides as (lo, hi) uint32 planes and
-  comes back packed. Shared by batched_downsample and the lease batcher."""
-  if is_u64_mode:
-    with trace.span("pooling.u64_split",
-                    bytes=sum(int(i.nbytes) for i in imgs)):
-      lo, hi = _u64_plane_batches(imgs)
-    outs, _ = executor((lo, hi))
-    with trace.span("pooling.u64_pack",
-                    bytes=sum(int(ol.nbytes + oh.nbytes) for ol, oh in outs)):
-      return [_pack_u64_planes(ol, oh) for ol, oh in outs]
-  batch = np.stack([_to_batch_layout(i) for i in imgs])
-  outs, _ = executor(batch)
-  return outs
+  ChunkExecutor dispatch. The cutouts are copied into a host batch the
+  executor reuses from round to round; uint64 mode rides as (lo, hi)
+  uint32 planes and comes back packed into fresh arrays (the encode pool
+  owns them after the round). Shared by batched_downsample and the lease
+  batcher."""
+  sources = _batch_sources(imgs, is_u64_mode)
+  nbytes = sum(int(i.nbytes) for i in imgs)
+
+  def fill(batches, reused):
+    attrs = {"bytes": nbytes, "reused": int(reused)}
+    span = (trace.span("pooling.u64_split", **attrs) if is_u64_mode
+            else trace.span("pooling.stack", **attrs))
+    with span:
+      span.set(workers=_stack_into(batches, sources))
+
+  outs, _ = executor.dispatch_staged(
+    (len(imgs),) + sources[0][0].shape,
+    np.uint32 if is_u64_mode else np.result_type(*imgs), fill)
+  if not is_u64_mode:
+    return outs
+  span = trace.span("pooling.u64_pack",
+                    bytes=sum(int(ol.nbytes + oh.nbytes) for ol, oh in outs))
+  with span:
+    packed = [_pack_u64_planes(ol, oh) for ol, oh in outs]
+    span.set(workers=max(
+      (_plane_runs(ol.shape, ol.nbytes + oh.nbytes)[1] for ol, oh in outs),
+      default=1))
+  return packed
 
 
 def batched_downsample(

@@ -10,6 +10,7 @@ scalar statistics (voxel counts, histograms) without host round-trips.
 from __future__ import annotations
 
 import re
+import threading
 from collections import OrderedDict
 from functools import partial
 from typing import List, Optional, Sequence, Tuple
@@ -98,6 +99,9 @@ class LRUCache:
 
 
 _CHUNK_EXECUTOR_CACHE = {}
+# input signatures a ChunkExecutor keeps free host batches for: a lease
+# batcher's full rounds and its last, shorter one
+STAGING_SIGNATURES = 2
 
 
 def cached_chunk_executor(
@@ -321,6 +325,10 @@ class ChunkExecutor:
     self._fn = self._build()
     # input signature -> AOT executable (ISSUE 7); LRU-bounded (ISSUE 19)
     self._compiled = LRUCache()
+    # (shape, dtype) -> host batches free for the next dispatch_staged;
+    # a worker that drifts through task shapes keeps the latest few
+    self._staging = LRUCache(STAGING_SIGNATURES)
+    self._staging_lock = threading.Lock()
     # persistent-cache key component: the pyramid configuration this
     # closure bakes in (name+signature alone cannot distinguish two
     # factor chains of equal shapes) and the program's module name
@@ -487,6 +495,33 @@ class ChunkExecutor:
       mesh=self.mesh,
     ):
       return self._finish_call(outs, nonzero, k)
+
+  def dispatch_staged(self, shape, dtype, fill):
+    """``self(batch)`` on a host batch of ``planes`` arrays of ``shape``
+    and ``dtype`` that ``fill(arrays, reused)`` writes first. The arrays
+    come from this executor's free list (``reused`` True: their pages
+    are already mapped, so the fill takes no page faults), or are
+    allocated when none is free, so concurrent callers never share
+    them. They go back on the list only after the dispatch has returned:
+    the device then holds its own copy and the call's device arrays have
+    been dropped (JAX's CPU backend may alias host memory in
+    ``device_put``). A failed dispatch drops them. The outputs are fresh
+    arrays, never staged."""
+    key = (tuple(int(v) for v in shape), np.dtype(dtype).str)
+    with self._staging_lock:
+      free = self._staging.get(key)
+      arrays = free.pop() if free else None
+    reused = arrays is not None
+    if not reused:
+      arrays = tuple(np.empty(shape, dtype) for _ in range(self.planes))
+    fill(arrays, reused)
+    result = self(arrays if self.planes == 2 else arrays[0])
+    with self._staging_lock:
+      free = self._staging.get(key)
+      if free is None:
+        free = self._staging[key] = []
+      free.append(arrays)
+    return result
 
   def _finish_call(self, outs, nonzero, k):
     if self.planes == 2:

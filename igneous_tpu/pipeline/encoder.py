@@ -138,6 +138,7 @@ def shared_encode_pool() -> EncodePool:
 
 _SHARED_IO: Optional[cf.ThreadPoolExecutor] = None
 _SHARED_PREFETCH: Optional[cf.ThreadPoolExecutor] = None
+_SHARED_COPY: Optional[cf.ThreadPoolExecutor] = None
 
 
 def shared_io_pool() -> cf.ThreadPoolExecutor:
@@ -167,3 +168,20 @@ def shared_prefetch_pool() -> cf.ThreadPoolExecutor:
         thread_name_prefix="ig-pipeline-prefetch",
       )
     return _SHARED_PREFETCH
+
+
+def shared_copy_pool() -> cf.ThreadPoolExecutor:
+  """Persistent pool for host memory copies cut into pieces (the batched
+  dispatch's staging, ``ops.pooling._copy_by_planes``), one thread a
+  core: numpy lets go of the GIL while it copies. DISTINCT from
+  shared_io_pool, where the next round's prefetch decodes while this
+  round's batch is copied, so the copy never queues behind it; and from
+  shared_prefetch_pool for the same-pool deadlock that pool's docstring
+  gives."""
+  global _SHARED_COPY
+  with _SHARED_LOCK:
+    if _SHARED_COPY is None:
+      _SHARED_COPY = cf.ThreadPoolExecutor(
+        max_workers=config._cores(), thread_name_prefix="ig-pipeline-copy"
+      )
+    return _SHARED_COPY

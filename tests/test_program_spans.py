@@ -199,6 +199,19 @@ def u64_round(tmp_path_factory):
   return _events(logdir)
 
 
+@pytest.mark.parametrize("mode", ["sampled", "unsampled"])
+def test_uint8_batch_stack_is_its_own_span(rounds, mode):
+  events = rounds[mode][0]
+  (dispatch,) = _named(events, "lease.round.dispatch")
+  (stack,) = _named(events, "pooling.stack")
+  (execute,) = _named(events, "device.execute")
+  assert _inside(stack, dispatch) and stack[3] <= execute[2]
+  assert stack[4]["bytes"] == 2 * TASK_VOXELS  # two uint8 cutouts
+  assert stack[4]["workers"] == 1  # below the copy pool's threshold
+  # the unsampled round runs second, on the executor the first one used
+  assert stack[4]["reused"] == 1 or mode == "sampled"
+
+
 def test_u64_planes_split_and_pack_carry_bytes(u64_round):
   (dispatch,) = _named(u64_round, "lease.round.dispatch")
   (split,) = _named(u64_round, "pooling.u64_split")
@@ -208,6 +221,44 @@ def test_u64_planes_split_and_pack_carry_bytes(u64_round):
   assert split[3] <= execute[2] and execute[3] <= pack[2]
   assert split[4]["bytes"] == 2 * TASK_VOXELS * 8  # two uint64 cutouts
   assert pack[4]["bytes"] == 2 * TASK_VOXELS // 4 * 8  # their mip 1
+
+
+def test_u64_planes_split_and_pack_carry_workers_and_reused(u64_round):
+  (split,) = _named(u64_round, "pooling.u64_split")
+  (pack,) = _named(u64_round, "pooling.u64_pack")
+  # tiny cutouts copy inline; a fresh executor has no batch to reuse
+  assert (split[4]["workers"], split[4]["reused"]) == (1, 0)
+  assert pack[4]["workers"] == 1
+
+
+@pytest.fixture(scope="module")
+def staged_rounds(tmp_path_factory):
+  """Two dispatches on each of two fresh executors, uint8 average and
+  uint64 mode, under the profiler."""
+  import jax
+
+  from igneous_tpu.parallel.batch_runner import device_pyramid_batch
+
+  rng = np.random.default_rng(5)
+  logdir = str(tmp_path_factory.mktemp("staged"))
+  assert not device_mod._PROFILE_STATE["active"]
+  jax.profiler.start_trace(logdir)
+  try:
+    for method, planes, dtype in (("average", 1, np.uint8),
+                                  ("mode", 2, np.uint64)):
+      ex = ChunkExecutor(make_mesh(2), factors=((2, 2, 1),), method=method,
+                         planes=planes)
+      for _ in range(2):
+        imgs = [rng.integers(0, 200, SHAPE).astype(dtype) for _ in range(2)]
+        device_pyramid_batch(ex, imgs, planes == 2)
+  finally:
+    jax.profiler.stop_trace()
+  return _events(logdir)
+
+
+@pytest.mark.parametrize("name", ["pooling.stack", "pooling.u64_split"])
+def test_staged_batch_is_reused_from_the_second_round(staged_rounds, name):
+  assert [e[4]["reused"] for e in _named(staged_rounds, name)] == [0, 1]
 
 
 def test_device_execute_carries_slab_walk(u64_round):
