@@ -19,7 +19,7 @@ NOISE_SUFFIXES = (".pyc", ".pyo", ".pyd")
 
 # lint scope: the package, repo tooling, and the root-level scripts
 LINT_ROOTS = ("igneous_tpu", "tools")
-LINT_ROOT_FILES = ("bench.py", "chip_smoke.py", "setup.py")
+LINT_ROOT_FILES = ("chip_smoke.py", "setup.py")
 
 
 def walk_files(root: str,

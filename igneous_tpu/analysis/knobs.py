@@ -136,17 +136,14 @@ _knob("IGNEOUS_PAGE_BATCH", "int", 32,
       "device count)", "paged batching")
 
 # --- compile cache / autotune (ISSUE 19) ----------------------------------
-_knob("IGNEOUS_COMPILE_CACHE", "str", None,
-      "persistent AOT-executable cache root (`gs://…`|`file://…`); "
-      "workers fetch serialized executables instead of compiling; unset "
-      "disables", "compile cache / autotune")
 _knob("IGNEOUS_EXECUTOR_CACHE_CAP", "int", 64,
       "max compiled signatures held per in-process executor cache "
       "(least-recently-used eviction)", "compile cache / autotune")
 _knob("IGNEOUS_TUNE_CONFIG", "str", None,
-      "tuned-config root override; unset reads `tuned/<device_kind>.json` "
-      "under IGNEOUS_COMPILE_CACHE (knob resolution: explicit env > "
-      "tuned config > registry default)", "compile cache / autotune")
+      "tuned-config root (`gs://…`|`file://…`) holding "
+      "`tuned/<device_kind>.json`; unset means no tuned layer (knob "
+      "resolution: explicit env > tuned config > registry default)",
+      "compile cache / autotune")
 _knob("IGNEOUS_TUNE_BUDGET_SEC", "float", None,
       "`igneous tune` wall-clock budget in seconds; unset sweeps every "
       "candidate", "compile cache / autotune")

@@ -3309,7 +3309,7 @@ def lint_cmd(root, knobs_md, write, baseline, update_baseline, select,
 @main.command("tune")
 @click.option("--out", default=None,
               help="Config root to write tuned/<device_kind>.json under "
-                   "(default: IGNEOUS_TUNE_CONFIG or IGNEOUS_COMPILE_CACHE).")
+                   "(default: IGNEOUS_TUNE_CONFIG).")
 @click.option("--budget", "budget_sec", type=float, default=None,
               help="Wall-clock budget for the whole sweep in seconds "
                    "(default: IGNEOUS_TUNE_BUDGET_SEC; unset = unbounded).")
@@ -3368,8 +3368,7 @@ def tune_cmd(out, budget_sec, repeats, size, only, as_json):
     click.echo(f"wrote {config['written_to']}")
   else:
     click.echo("no config root resolvable (pass --out or set "
-               "IGNEOUS_TUNE_CONFIG / IGNEOUS_COMPILE_CACHE); "
-               "config not persisted")
+               "IGNEOUS_TUNE_CONFIG); config not persisted")
 
 
 @main.command("license")

@@ -204,14 +204,8 @@ class InferenceModel:
     key = tuple(d.id for d in mesh.devices.flat)
     with self._lock:
       if key not in self._executors:
-        # cache_variant (ISSUE 19): the spec is the program identity —
-        # params ride as runtime consts (their shapes live in the input
-        # signature), but architecture/width choices shape the kernel
         self._executors[key] = BatchKernelExecutor(
           self.apply, mesh=mesh, name=self.kernel_name,
-          cache_variant=(
-            "infer", tuple(sorted(self.spec.to_dict().items())),
-          ),
         )
       return self._executors[key]
 

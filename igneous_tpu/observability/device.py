@@ -16,7 +16,7 @@ folklore, not a number. This module is the instrument:
   track. Each is a ``trace.span``, so a profiler trace also shows it as
   ``igneous.device.<phase>`` with its ``kernel`` (and ``bytes``) beside
   the device's own operations.
-* **Compile-cache / shape-churn ledger** — distinct compiled signatures
+* **Recompile / shape-churn ledger** — distinct compiled signatures
   per kernel are counted; ``device.recompiles`` increments exactly once
   per NEW signature (the ragged-batching baseline number), and the
   fast-path eligibility gauge tracks batched vs fell-to-host deliveries.
@@ -102,12 +102,6 @@ class DeviceLedger:
       self.recompiles = 0  # guarded-by: self.lock
       self.dispatches = 0  # guarded-by: self.lock
       self.fastpath = {"batched": 0, "host": 0}  # guarded-by: self.lock
-      # persistent compile-cache accounting (ISSUE 19): saved_s sums the
-      # producer-measured compile seconds each hit avoided — the number
-      # `igneous fleet devices` rolls up into compile-seconds-saved
-      self.compile_cache = dict(  # guarded-by: self.lock
-        hits=0, misses=0, puts=0, corrupt=0, saved_s=0.0, fetch_s=0.0,
-      )
       # padding-byte accounting across every batched dispatch (pow2
       # batch rounding, page-pool filler slots, infer group fill)
       self.pad_bytes = 0  # guarded-by: self.lock
@@ -124,54 +118,24 @@ class DeviceLedger:
       k = self.kernels[name] = {
         "compiles": 0, "compile_s": 0.0,
         "executes": 0, "execute_s": 0.0,
-        "elements": 0, "bytes": 0, "cache_hits": 0,
+        "elements": 0, "bytes": 0,
       }
     return k
 
   # -- write side -----------------------------------------------------------
 
-  def note_signature(self, kernel: str, signature,
-                     cached: bool = False) -> bool:
+  def note_signature(self, kernel: str, signature) -> bool:
     """True exactly once per (kernel, signature): the recompile tick.
     Counter contract (ISSUE 7 acceptance): ``device.recompiles``
-    increments ONLY when a shape/dtype signature is first compiled.
-
-    ``cached=True`` marks a persistent compile-cache hit (ISSUE 19): the
-    signature still enters the seen-set, but ``device.recompiles`` does
-    NOT tick — a warm-started fleet fetched the executable instead of
-    compiling, and must not trip the recompile-storm anomaly or skew
-    ``igneous_device_fastpath_ratio`` baselines."""
+    increments ONLY when a shape/dtype signature is first compiled."""
     key = (kernel, repr(signature))
     with self.lock:
       if key in self._signatures:
         return False
       self._signatures.add(key)
-      if not cached:
-        self.recompiles += 1
-    if not cached:
-      metrics.incr("device.recompiles")
+      self.recompiles += 1
+    metrics.incr("device.recompiles")
     return True
-
-  _CACHE_COUNTER = {"hits": "hit", "misses": "miss",
-                    "puts": "put", "corrupt": "corrupt"}
-
-  def record_cache_event(self, event: str, kernel: str = "",
-                         saved_s: float = 0.0,
-                         fetch_s: float = 0.0) -> None:
-    """Persistent compile-cache accounting (ISSUE 19): ``event`` is one
-    of hits|misses|puts|corrupt. ``saved_s`` is the producer-measured
-    compile time a hit avoided; ``fetch_s`` the deserialize+download
-    cost actually paid instead."""
-    with self.lock:
-      cc = self.compile_cache
-      cc[event] += 1
-      cc["saved_s"] += float(saved_s)
-      cc["fetch_s"] += float(fetch_s)
-      if kernel and event == "hits":
-        k = self._kernel_locked(kernel)
-        k["cache_hits"] = k.get("cache_hits", 0) + 1
-      self._dirty = True
-    metrics.incr(f"device.compile_cache.{self._CACHE_COUNTER[event]}")
 
   def record_compile(self, kernel: str, seconds: float) -> None:
     with self.lock:
@@ -340,10 +304,6 @@ class DeviceLedger:
           dev: round(s, 4) for dev, s in sorted(self.device_busy.items())
         },
         "fastpath": dict(self.fastpath),
-        "compile_cache": {
-          k: (round(v, 4) if isinstance(v, float) else v)
-          for k, v in self.compile_cache.items()
-        },
         "pad_bytes": self.pad_bytes,
         "real_bytes": self.real_bytes,
         "pad_waste_ratio": (
@@ -425,29 +385,10 @@ def _devices_of(mesh=None) -> List[str]:
     return ["device"]
 
 
-def record_span(name: str, seconds: float, **attrs) -> None:
-  """A pre-measured device span (a compile-cache fetch), journaled as
-  the compile/execute/transfer spans below are: under a sampled
-  task/stage context it nests there (``fleet trace`` shows the device
-  work inside the task that caused it); otherwise it lands on the worker
-  trace — lease-round dispatches and batched workloads happen outside
-  any task span, and their device timeline must not vanish for it. The
-  spans below are ``trace.span(..., worker=True)`` regions, on
-  the profiler's clock too."""
-  ctx = trace.current()
-  if ctx is not None:
-    if ctx.sampled:
-      # lint: allow=IGN503 forwards the caller's device span name
-      trace.record_span(name, seconds, **attrs)
-    return  # unsampled task: honor its sampling verdict
-  if trace.tracing_enabled():
-    trace.record_root(name, time.time() - seconds, seconds, **attrs)
-
-
 @contextlib.contextmanager
 def compile_span(kernel: str, devices: List[str]) -> Iterator[None]:
-  """Time one XLA compilation (lower+compile, or the first traced call
-  of a fresh signature) and account it to the ledger."""
+  """Time one XLA compilation (lower+compile) and account it to the
+  ledger."""
   sp = trace.span("device.compile", worker=True, kernel=kernel,
                   device=devices[0] if devices else None)
   t0 = time.perf_counter()
@@ -863,29 +804,7 @@ def render_devices(ledgers: Dict[str, dict]) -> List[str]:
       f"pad waste: {_fmt_bytes(pad)} padding over {_fmt_bytes(real)} real "
       f"bytes ({pad / real:.1%})"
     )
-  cc = _cache_rollup(ledgers)
-  if cc["hits"] or cc["misses"] or cc["puts"] or cc["corrupt"]:
-    lines.append(
-      f"compile cache: {cc['hits']} hits / {cc['misses']} misses, "
-      f"{cc['puts']} puts, {cc['corrupt']} corrupt — "
-      f"{cc['saved_s']:.1f}s compile time saved fleet-wide "
-      f"({cc['fetch_s']:.1f}s spent fetching)"
-    )
   return lines
-
-
-def _cache_rollup(ledgers: Dict[str, dict]) -> dict:
-  """Summed persistent compile-cache stats across every worker's latest
-  ledger record — the fleet-wide compile-seconds-saved number."""
-  cc = {"hits": 0, "misses": 0, "puts": 0, "corrupt": 0,
-        "saved_s": 0.0, "fetch_s": 0.0}
-  for rec in ledgers.values():
-    src = rec.get("compile_cache") or {}
-    for key in cc:
-      cc[key] += type(cc[key])(src.get(key, 0) or 0)
-  cc["saved_s"] = round(cc["saved_s"], 4)
-  cc["fetch_s"] = round(cc["fetch_s"], 4)
-  return cc
 
 
 def fleet_summary(ledgers: Dict[str, dict]) -> Optional[dict]:
@@ -921,7 +840,6 @@ def fleet_summary(ledgers: Dict[str, dict]) -> Optional[dict]:
     "recompiles": sum(r.get("recompiles", 0) for r in ledgers.values()),
     "hbm_peak_frac": round(hbm_frac, 4) if hbm_frac is not None else None,
     "fastpath": fp,
-    "compile_cache": _cache_rollup(ledgers),
   }
 
 

@@ -591,7 +591,6 @@ def _batch_executor(connectivity: int, mesh=None):
       ),
       mesh=mesh,
       name=f"ccl.tiled[{algo}]",
-      cache_variant=("ccl_tiled", connectivity, algo, tile),
     )
   return _BATCH_EXECUTORS[key]
 

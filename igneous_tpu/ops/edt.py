@@ -509,7 +509,6 @@ def batch_edt_executor(anisotropy, mesh=None):
       _partial(_edt_sq_kernel, anisotropy=(wx, wy, wz), line_block=lb),
       mesh=mesh,
       name="edt.sq_blocked",
-      cache_variant=("edt", wx, wy, wz, lb),
     )
   return _BATCH_EXECUTORS[key]
 

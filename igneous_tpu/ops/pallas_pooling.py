@@ -13,10 +13,7 @@ op (one 2x2x1 average/mode pooling step) for TPU:
     as ops/pooling._pool_mode via 4 static window slices.
 
 Use ``available()`` / ``pool2x2x1`` with ``interpret=True`` for CPU tests.
-The task pipeline keeps the XLA path; this kernel is the promotion
-CANDIDATE — bench.py records the device-resident Pallas-vs-XLA A/B on
-every TPU run (detail.pool_ab), and the pyramid switches only when that
-evidence says so (ROADMAP item 1).
+The task pipeline keeps the XLA path; no program runs this kernel.
 """
 
 from __future__ import annotations

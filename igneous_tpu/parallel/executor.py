@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import compile_cache
 from ..analysis import knobs
 from ..observability import device as device_telemetry
 from ..ops.pooling import _pyramid_impl, device_memory_bytes, slab_depth
@@ -49,6 +48,20 @@ def _named_jit(fn, kernel: str):
   return jax.jit(program)
 
 
+def compile_program(kernel: str, signature, mesh, lower):
+  """The executors' one compile route: tick the recompile ledger for
+  ``signature``, then compile ``lower()`` (a ``jax.stages.Lowered``)
+  ahead of time inside a ``device.compile`` span. Compiling apart from
+  the first call keeps XLA's work out of the first ``device.execute``
+  span; JAX's persistent cache (``compile_cache.place_jax_cache``)
+  serves a program another process already compiled."""
+  device_telemetry.LEDGER.note_signature(kernel, signature)
+  with device_telemetry.compile_span(
+    kernel, device_telemetry._devices_of(mesh)
+  ):
+    return lower().compile()
+
+
 def make_mesh(n_devices: Optional[int] = None, axis: str = "chunks") -> Mesh:
   devices = jax.devices()
   if n_devices is not None:
@@ -62,9 +75,8 @@ class LRUCache:
   signatures must not hold every executable it ever compiled. Cap from
   ``IGNEOUS_EXECUTOR_CACHE_CAP``; least-recently-USED eviction (both
   lookup and insert refresh recency). Eviction is safe — a re-needed
-  signature recompiles (or refetches from the persistent cache) without
-  a fresh ``device.recompiles`` tick, since the ledger seen-set is
-  independent of this cache."""
+  signature recompiles without a fresh ``device.recompiles`` tick,
+  since the ledger seen-set is independent of this cache."""
 
   def __init__(self, cap: Optional[int] = None):
     if cap is None:
@@ -151,23 +163,11 @@ class BatchKernelExecutor:
   """
 
   def __init__(self, kernel, mesh: Optional[Mesh] = None,
-               name: Optional[str] = None, cache_variant=None):
-    """``cache_variant`` (ISSUE 19): a stable tuple of the kernel's
-    closure configuration (factors, tile, anisotropy, model spec…) that
-    the name+signature alone cannot capture. Declaring it opts this
-    executor into the persistent compile cache; None keeps the site
-    compile-only — two differently-configured kernels sharing a name
-    must never exchange executables."""
+               name: Optional[str] = None):
     self.kernel = kernel
     self.name = name or getattr(kernel, "__name__", "kernel").lstrip("_")
     self.mesh = mesh if mesh is not None else make_mesh()
     self.axis = self.mesh.axis_names[0]
-    # the program's module name is part of the variant: an executable
-    # stored under another name never loads under this one
-    self.cache_variant = (
-      None if cache_variant is None
-      else (cache_variant, "jit_" + program_name(self.name))
-    )
     self._cache = LRUCache()
     self._consts_cache = LRUCache()
 
@@ -265,16 +265,9 @@ class BatchKernelExecutor:
       dev = jax.tree.map(lambda a: jax.device_put(a, sharding), batch)
     argv = (dev,) if consts is None else (consts, dev)
     if sig not in self._cache:
-      # device.compile vs device.execute split (ISSUE 7): AOT
-      # lower+compile so the compile span measures XLA work alone —
-      # jit's lazy first-call compile would fold it into the first
-      # execute and poison the utilization ledger. load_or_compile
-      # (ISSUE 19) consults the persistent cache first when one is
-      # configured and this executor declared its cache_variant.
-      self._cache[sig] = compile_cache.load_or_compile(
+      self._cache[sig] = compile_program(
         self.name, sig, self.mesh,
-        lambda: self._build(batch, consts).lower(*argv).compile(),
-        variant=self.cache_variant,
+        lambda: self._build(batch, consts).lower(*argv),
       )
     with device_telemetry.execute_span(
       self.name, elements=device_telemetry.elements_of(batch),
@@ -329,13 +322,6 @@ class ChunkExecutor:
     # a worker that drifts through task shapes keeps the latest few
     self._staging = LRUCache(STAGING_SIGNATURES)
     self._staging_lock = threading.Lock()
-    # persistent-cache key component: the pyramid configuration this
-    # closure bakes in (name+signature alone cannot distinguish two
-    # factor chains of equal shapes) and the program's module name
-    self.cache_variant = (
-      "pyramid", self.factors, method, bool(sparse), self.planes,
-      "jit_" + program_name(self.name),
-    )
 
   def slab_depth(self, shard_shape, dtypes) -> int:
     """The z-slab depth the pyramid walks for one device's shard: planes
@@ -416,40 +402,15 @@ class ChunkExecutor:
     if len(arrs) != self.planes:
       raise ValueError(f"expected {self.planes} plane(s), got {len(arrs)}")
     sig = ("global",) + tuple((a.shape, str(a.dtype)) for a in arrs)
-    # the persistent cache (ISSUE 19) prefers the AOT route so a warm
-    # worker skips the compile entirely (load_or_compile falls back to
-    # compiling on a failed fetch); the plain-jit path below stays the
-    # default when no cache is configured
-    if compile_cache.get_active() is not None:
-      compiled = self._compiled.get(sig)
-      if compiled is None:
-        compiled = compile_cache.load_or_compile(
-          self.name, sig, self.mesh,
-          lambda: self._fn.lower(tuple(arrs)).compile(),
-          variant=self.cache_variant + ("global",),
-        )
-        self._compiled[sig] = compiled
-      with device_telemetry.execute_span(
-        self.name, elements=device_telemetry.elements_of(arrs),
-        mesh=self.mesh, **self._walk_attrs(arrs), **self.span_attrs,
-      ):
-        out = compiled(tuple(arrs))
-        jax.block_until_ready(out)
-      return out
-    # multihost default keeps the plain jit; first-call-per-signature
-    # still ticks the recompile ledger and labels as compile
-    fresh = device_telemetry.LEDGER.note_signature(self.name, sig)
-    span = (
-      device_telemetry.compile_span(
-        self.name, device_telemetry._devices_of(self.mesh)
-      ) if fresh else
-      device_telemetry.execute_span(
-        self.name, elements=device_telemetry.elements_of(arrs),
-        mesh=self.mesh, **self._walk_attrs(arrs), **self.span_attrs,
+    if sig not in self._compiled:
+      self._compiled[sig] = compile_program(
+        self.name, sig, self.mesh, lambda: self._fn.lower(tuple(arrs)),
       )
-    )
-    with span:
-      out = self._fn(tuple(arrs))
+    with device_telemetry.execute_span(
+      self.name, elements=device_telemetry.elements_of(arrs),
+      mesh=self.mesh, **self._walk_attrs(arrs), **self.span_attrs,
+    ):
+      out = self._compiled[sig](tuple(arrs))
       jax.block_until_ready(out)
     return out
 
@@ -478,10 +439,8 @@ class ChunkExecutor:
       xs = tuple(jax.device_put(p, sharding) for p in padded)
     sig = tuple((a.shape, str(a.dtype)) for a in xs)
     if sig not in self._compiled:
-      self._compiled[sig] = compile_cache.load_or_compile(
-        self.name, sig, self.mesh,
-        lambda: self._fn.lower(xs).compile(),
-        variant=self.cache_variant,
+      self._compiled[sig] = compile_program(
+        self.name, sig, self.mesh, lambda: self._fn.lower(xs),
       )
     with device_telemetry.execute_span(
       self.name, elements=sum(int(p.size) for p in padded),

@@ -66,7 +66,9 @@ from ..ops.pooling import (
   _split_u64_planes,
   _to_device_layout,
 )
-from .executor import BatchKernelExecutor, LRUCache, make_mesh
+from .executor import (
+  BatchKernelExecutor, LRUCache, compile_program, make_mesh,
+)
 
 from .. import tune
 from ..analysis import knobs
@@ -215,9 +217,6 @@ def paged_pyramid_executor(
       _make_page_kernel(factors, method, sparse, planes),
       mesh=mesh,
       name=f"pooling.paged_pyramid[{method}]",
-      cache_variant=(
-        "paged_pyramid", factors, method, bool(sparse), int(planes)
-      ),
     )
   return _PAGED_EXECUTORS[key]
 
@@ -470,7 +469,6 @@ def _paged_ccl_executor(connectivity: int, mesh=None):
       ),
       mesh=mesh,
       name=f"ccl.paged[{algo}]",
-      cache_variant=("ccl_paged", connectivity, algo, tile),
     )
   return _PAGED_CCL_EXECUTORS[key]
 
@@ -591,7 +589,6 @@ def _paged_edt_executor(anisotropy, mesh=None):
       partial(_edt_sq_kernel, anisotropy=(wx, wy, wz), line_block=lb),
       mesh=mesh,
       name="edt.sq_paged",
-      cache_variant=("edt_paged", wx, wy, wz, lb),
     )
   return _PAGED_EDT_EXECUTORS[key]
 
@@ -673,11 +670,8 @@ class PagedGlobalRunner:
     self._kernel = _make_page_kernel(
       self.factors, method, sparse, self.planes
     )
-    self.cache_variant = (
-      "paged_global", self.factors, method, bool(sparse), self.planes
-    )
-    self._fns = LRUCache()
-    self._aot = LRUCache()
+    # input signature -> AOT executable
+    self._compiled = LRUCache()
 
   def _make(self, tree):
     """The shard_map'd jit closure for one input structure."""
@@ -699,46 +693,18 @@ class PagedGlobalRunner:
   def __call__(self, pages, exts):
     """pages: global (K, c, pz, py, px) jax.Array (or a (lo, hi) tuple,
     planes=2); exts: global (K, 3) int32. Returns per-mip global arrays."""
-    from .. import compile_cache
-
     tree = (pages, exts)
     leaves = jax.tree.leaves(tree)
     sig = tuple((tuple(a.shape), str(a.dtype)) for a in leaves)
-    # persistent cache path (ISSUE 19): a warm worker fetches the AOT
-    # executable instead of compiling (load_or_compile falls back to
-    # compiling on a failed fetch); the plain-jit path below is the
-    # default when no cache is configured
-    if compile_cache.get_active() is not None:
-      compiled = self._aot.get(sig)
-      if compiled is None:
-        compiled = compile_cache.load_or_compile(
-          self.name, sig, self.mesh,
-          lambda: self._make(tree).lower(tree).compile(),
-          variant=self.cache_variant,
-        )
-        self._aot[sig] = compiled
-      with device_telemetry.execute_span(
-        self.name,
-        elements=sum(int(np.prod(a.shape)) for a in leaves),
-        mesh=self.mesh,
-      ):
-        out = compiled(tree)
-        jax.block_until_ready(out)
-      return out
-    if sig not in self._fns:
-      self._fns[sig] = self._make(tree)
-    fresh = device_telemetry.LEDGER.note_signature(self.name, sig)
-    span = (
-      device_telemetry.compile_span(
-        self.name, device_telemetry._devices_of(self.mesh)
-      ) if fresh else
-      device_telemetry.execute_span(
-        self.name,
-        elements=sum(int(np.prod(a.shape)) for a in leaves),
-        mesh=self.mesh,
+    if sig not in self._compiled:
+      self._compiled[sig] = compile_program(
+        self.name, sig, self.mesh, lambda: self._make(tree).lower(tree),
       )
-    )
-    with span:
-      out = self._fns[sig](tree)
+    with device_telemetry.execute_span(
+      self.name,
+      elements=sum(int(np.prod(a.shape)) for a in leaves),
+      mesh=self.mesh,
+    ):
+      out = self._compiled[sig](tree)
       jax.block_until_ready(out)
     return out

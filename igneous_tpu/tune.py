@@ -8,7 +8,7 @@ EDT line-block geometry, and page shape/batch on seeded representative
 workloads, assert every candidate's output is byte-identical to the
 default path (these knobs are performance-only by construction — any
 divergence is a kernel bug and fails the sweep), and persist the winners
-as ``tuned/<device_kind>.json`` next to the compile cache's executables.
+as ``tuned/<device_kind>.json`` under a config root.
 
 Knob resolution order for the tunables, everywhere they are read::
 
@@ -16,9 +16,8 @@ Knob resolution order for the tunables, everywhere they are read::
 
 so an operator override always wins, a fleet with a published tuned
 config picks it up with zero env plumbing, and everyone else keeps the
-registry defaults. The config root is ``IGNEOUS_TUNE_CONFIG`` when set,
-else ``IGNEOUS_COMPILE_CACHE`` — the moment a real TPU round runs, tuned
-configs and warm executables land together as durable fleet artifacts.
+registry defaults. The config root is ``IGNEOUS_TUNE_CONFIG``; unset,
+there is no tuned layer.
 """
 
 from __future__ import annotations
@@ -56,11 +55,7 @@ def device_kind() -> str:
 
 
 def config_root() -> Optional[str]:
-  return (
-    knobs.get_str(CONFIG_ENV)
-    or knobs.get_str("IGNEOUS_COMPILE_CACHE")
-    or None
-  )
+  return knobs.get_str(CONFIG_ENV) or None
 
 
 # [loaded?, config-or-None]: the tuned config is read at most once per

@@ -1,11 +1,11 @@
 import os
 
 # Tests run on a virtual 8-device CPU mesh so multi-chip sharding logic is
-# exercised without TPU hardware; only chip_smoke.py and bench.py run on
-# the chip. The env vars are set before jax is imported and are inherited
-# by subprocesses (LocalTaskQueue spawn workers, CLI children). JAX's
-# persistent compilation cache stays off, so CPU executables never land
-# in the checkout's .jax_cache.
+# exercised without TPU hardware; only chip_smoke.py and benchmark/run.py
+# run on the chip. The env vars are set before jax is imported and are
+# inherited by subprocesses (LocalTaskQueue spawn workers, CLI children).
+# JAX's persistent compilation cache stays off, so CPU executables never
+# land in the checkout's .jax_cache.
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 xla_flags = os.environ.get("XLA_FLAGS", "")

@@ -29,7 +29,12 @@ from igneous_tpu.observability import (
   rollup,
   trace,
 )
-from igneous_tpu.parallel.executor import BatchKernelExecutor
+from igneous_tpu.ops.oracle import (
+  np_downsample_segmentation, np_downsample_with_averaging,
+)
+from igneous_tpu.parallel.executor import (
+  BatchKernelExecutor, ChunkExecutor, make_mesh,
+)
 from igneous_tpu.pipeline import run_tasks_pipelined
 from igneous_tpu.volume import Volume
 
@@ -50,20 +55,131 @@ def _clean_device_plane():
 # -- recompile ledger ---------------------------------------------------------
 
 
-def test_recompile_counter_fires_once_per_new_signature():
-  ex = BatchKernelExecutor(lambda x: x + 1, name="tkern")
-  ex(np.ones((4, 4, 4), np.float32))
-  ex(np.ones((4, 4, 4), np.float32))   # same signature: cache hit
-  ex(np.ones((4, 8, 8), np.float32))   # new shape: one recompile
-  ex(np.ones((4, 8, 8), np.float64))   # new dtype: one recompile
-  ex(np.ones((4, 8, 8), np.float64))   # hit again
-  assert telemetry.counters_snapshot()["device.recompiles"] == 3
-  snap = device_mod.LEDGER.snapshot()
-  k = snap["kernels"]["tkern"]
-  assert k["compiles"] == 3
-  assert k["executes"] == 5
-  assert snap["distinct_signatures"] == 3
-  # compile time measured apart from execute time (AOT lower+compile)
+def _xyz_oracle(chunks, oracle):
+  """Mip 1 of each (c=1, z, y, x) chunk under ``oracle`` (an xyz numpy
+  pyramid), back in (k, 1, z, y, x) order."""
+  return np.stack([
+    oracle(c[0].transpose(2, 1, 0), (2, 2, 1), 1)[0].transpose(2, 1, 0)[None]
+    for c in chunks
+  ])
+
+
+# variant 0 -> 1 changes the shape, 1 -> 2 only the dtype: both are new
+# signatures
+_DTYPES = (np.uint8, np.uint8, np.uint16)
+
+
+def _u8_chunks(k, y=8, dtype=np.uint8):
+  rng = np.random.default_rng(k * 100 + y)
+  return rng.integers(0, 255, (k, 1, 4, y, 8)).astype(dtype)
+
+
+def _run_batch(variant, executors):
+  ex = executors.setdefault(
+    "batch", BatchKernelExecutor(lambda x: x * 2, name="tkern")
+  )
+  x = np.ones((4, 4, 4 * min(variant + 1, 2)),
+              (np.float32, np.float32, np.float64)[variant])
+  return "tkern", ex(x), x * 2
+
+
+def _run_chunk(variant, executors):
+  ex = executors.setdefault("chunk", ChunkExecutor(
+    make_mesh(2), factors=((2, 2, 1),), method="average"))
+  chunks = _u8_chunks(2, 8 * min(variant + 1, 2), _DTYPES[variant])
+  (mip,), _ = ex(chunks)
+  return ex.name, mip, _xyz_oracle(chunks, np_downsample_with_averaging)
+
+
+def _run_chunk_planes2(variant, executors):
+  ex = executors.setdefault("chunk_planes2", ChunkExecutor(
+    make_mesh(2), factors=((2, 2, 1),), method="mode", planes=2))
+  # the planes are always a uint32 pair: variant 2 changes the shape again
+  rng = np.random.default_rng(variant)
+  labels = rng.integers(0, 4, (2, 1, 4, 8 * (variant + 1), 8)).astype(
+    np.uint64) * np.uint64(2**40 + 3)
+  lo = (labels & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+  hi = (labels >> np.uint64(32)).astype(np.uint32)
+  ((mlo, mhi),), _ = ex((lo, hi))
+  got = mlo.astype(np.uint64) | (mhi.astype(np.uint64) << np.uint64(32))
+  return ex.name, got, _xyz_oracle(labels, np_downsample_segmentation)
+
+
+def _run_global(variant, executors):
+  import jax
+  from jax.sharding import NamedSharding, PartitionSpec as P
+
+  mesh = make_mesh(2)
+  ex = executors.setdefault("global", ChunkExecutor(
+    mesh, factors=((2, 2, 1),), method="average"))
+  chunks = _u8_chunks(2 * min(variant + 1, 2), dtype=_DTYPES[variant])
+  placed = jax.device_put(chunks, NamedSharding(mesh, P(mesh.axis_names[0])))
+  (mip,), _ = ex.run_global(placed)
+  return ex.name, np.asarray(mip), _xyz_oracle(
+    chunks, np_downsample_with_averaging)
+
+
+def _run_paged_global(variant, executors):
+  import jax
+  from jax.sharding import NamedSharding, PartitionSpec as P
+
+  from igneous_tpu.parallel.paged import PagedGlobalRunner
+
+  mesh = make_mesh(2)
+  runner = executors.setdefault("paged_global", PagedGlobalRunner(
+    ((2, 2, 1),), method="average", mesh=mesh))
+  pages = _u8_chunks(2 * min(variant + 1, 2), dtype=_DTYPES[variant])
+  exts = np.full((len(pages), 3), (4, 8, 8), np.int32)
+  rows = NamedSharding(mesh, P(mesh.axis_names[0]))
+  (mip,) = runner(jax.device_put(pages, rows), jax.device_put(exts, rows))
+  return runner.name, np.asarray(mip), _xyz_oracle(
+    pages, np_downsample_with_averaging)
+
+
+def _run_paged(variant, executors):
+  from igneous_tpu.parallel.paged import PagedPyramid
+
+  rng = np.random.default_rng(variant)
+  imgs = [rng.integers(0, 255, s).astype(_DTYPES[variant])
+          for s in [(20, 9, 5), (8, 8, 8)]]
+  p = PagedPyramid(imgs, (2, 2, 1), 1, method="average",
+                   page=(8, 8, 8 * min(variant + 1, 2)))
+  got = np.concatenate([mips[0].ravel() for mips in p.run()])
+  exp = np.concatenate([
+    np_downsample_with_averaging(img, (2, 2, 1), 1)[0].ravel()
+    for img in imgs
+  ])
+  return "pooling.paged_pyramid[average]", got, exp
+
+
+@pytest.mark.parametrize("run", [
+  _run_batch, _run_chunk, _run_chunk_planes2, _run_global,
+  _run_paged_global, _run_paged,
+], ids=["batch", "chunk", "chunk_planes2", "run_global", "paged_global",
+        "paged"])
+def test_recompile_counter_fires_once_per_new_signature(run, monkeypatch):
+  """Every device executor compiles through one route: a new input
+  signature ticks ``device.recompiles`` and the kernel's compiles once,
+  with compile time counted apart from execute time; a repeat signature
+  only executes."""
+  from igneous_tpu.parallel import paged
+
+  # fresh executors: a compiled signature held from an earlier test
+  # would skip the compile this test counts
+  monkeypatch.setattr(paged, "_PAGED_EXECUTORS", {})
+  executors = {}
+  ticks, executes = [], [0]
+  for variant in (0, 0, 1, 1, 2, 2):
+    name, got, exp = run(variant, executors)
+    np.testing.assert_array_equal(np.asarray(got), exp)
+    k = device_mod.LEDGER.snapshot()["kernels"][name]
+    ticks.append(
+      (telemetry.counters_snapshot()["device.recompiles"], k["compiles"])
+    )
+    executes.append(k["executes"])
+  assert ticks == [(1, 1), (1, 1), (2, 2), (2, 2), (3, 3), (3, 3)]
+  assert executes == list(range(7))  # every call executes exactly once
+  assert device_mod.LEDGER.snapshot()["distinct_signatures"] == 3
   assert k["compile_s"] > 0 and k["execute_s"] > 0
 
 

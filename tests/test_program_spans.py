@@ -332,6 +332,3 @@ def test_pyramid_program_is_named_after_its_kernel():
   assert program_name(ex.name) == "pooling_pyramid_average"
   x = jax.device_put(np.zeros((2, 1, 4, 8, 8), np.uint8))
   assert "@jit_pooling_pyramid_average" in ex._fn.lower((x,)).as_text()
-  # the persistent compile cache keys on the name: an executable stored
-  # under another module name never loads under this one
-  assert ex.cache_variant[-1] == "jit_pooling_pyramid_average"
