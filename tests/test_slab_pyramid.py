@@ -108,6 +108,111 @@ def test_z_not_a_multiple_of_the_z_factors_walks_one_slab(sparse):
                _expected(img, F222, "mode", sparse))
 
 
+WINDOW_SHAPES = [(1, 5, 7, 9), (2, 4, 8, 16), (1, 3, 5, 11)]
+WINDOW_FACTORS = [(2, 2, 1), (2, 2, 2), (3, 3, 1), (1, 2, 3)]
+
+
+def _edge_padded(x, f):
+  """(c, z, y, x) ``x`` edge-replicated to a multiple of ``f`` (x, y, z)."""
+  fx, fy, fz = f
+  _, z, y, w = x.shape
+  return np.pad(x, [(0, 0), (0, -z % fz), (0, -y % fy), (0, -w % fx)],
+                mode="edge")
+
+
+def _with_extremes(shape, dtype, seed):
+  """Values of ``dtype`` drawn from its extremes and a few between (NaN
+  and infinities for floats)."""
+  if np.dtype(dtype) == np.bool_:
+    pool = np.array([False, True])
+  elif np.issubdtype(dtype, np.floating):
+    pool = np.array([-np.inf, -1.5, -0.0, 0, 2.25, np.inf, np.nan], dtype)
+  else:
+    info = np.iinfo(dtype)
+    pool = np.array([info.min, info.min + 1, 0, 7, info.max - 1, info.max],
+                    dtype)
+  return pool[np.random.default_rng(seed).integers(0, len(pool), shape)]
+
+
+@pytest.mark.parametrize("dtype",
+                         [np.uint8, np.int16, np.uint32, np.float32, np.bool_],
+                         ids=lambda d: np.dtype(d).name)
+@pytest.mark.parametrize("f", WINDOW_FACTORS,
+                         ids=lambda f: "x".join(map(str, f)))
+@pytest.mark.parametrize("shape", WINDOW_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_window_slices_select_the_strided_views(shape, f, dtype):
+  """Each window position is the numpy strided view of the edge-padded
+  input, in z-major, then y, then x order, the dtype's extremes (and NaN)
+  included."""
+  x = _with_extremes(shape, dtype, sum(shape))
+  fx, fy, fz = f
+  padded = _edge_padded(x, f)
+  got = jax.jit(lambda a: pooling._window_slices(a, f))(jnp.asarray(x))
+  want = [padded[:, dz::fz, dy::fy, dx::fx]
+          for dz in range(fz) for dy in range(fy) for dx in range(fx)]
+  assert len(got) == len(want)
+  for g, w in zip(got, want):
+    assert g.dtype == w.dtype
+    np.testing.assert_array_equal(np.asarray(g), w)
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.uint16, np.int16],
+                         ids=lambda d: np.dtype(d).name)
+@pytest.mark.parametrize("f", WINDOW_FACTORS,
+                         ids=lambda f: "x".join(map(str, f)))
+@pytest.mark.parametrize("shape", WINDOW_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_narrow_int_average_is_the_rounded_window_mean(shape, f, dtype):
+  """The reduce-window sum of ≤16-bit integers: each window of the
+  edge-padded input, its mean rounded half up."""
+  info = np.iinfo(dtype)
+  x = np.random.default_rng(sum(shape) + 1).integers(
+    info.min, info.max, shape, endpoint=True).astype(dtype)
+  fx, fy, fz = f
+  padded = _edge_padded(x, f).astype(np.int64)
+  c, z, y, w = padded.shape
+  sums = padded.reshape(c, z // fz, fz, y // fy, fy, w // fx, fx).sum(
+    axis=(2, 4, 6))
+  n = fx * fy * fz
+  want = ((sums + n // 2) // n).astype(dtype)
+  got = jax.jit(lambda a: pooling._pool_average(a, f))(jnp.asarray(x))
+  assert got.dtype == want.dtype
+  np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("op", ["min", "max"])
+@pytest.mark.parametrize("dtype", [np.uint8, np.int16, np.uint32, np.float32],
+                         ids=lambda d: np.dtype(d).name)
+@pytest.mark.parametrize("f", WINDOW_FACTORS,
+                         ids=lambda f: "x".join(map(str, f)))
+@pytest.mark.parametrize("shape", WINDOW_SHAPES[:2],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_minmax_is_each_windows_extreme(shape, f, dtype, op):
+  """The min or max of each window of the edge-padded input, extremes of
+  the dtype included; a NaN in a window makes it NaN, as in numpy."""
+  x = _with_extremes(shape, dtype, sum(shape) + 2)
+  fx, fy, fz = f
+  padded = _edge_padded(x, f)
+  c, z, y, w = padded.shape
+  windows = padded.reshape(c, z // fz, fz, y // fy, fy, w // fx, fx)
+  want = getattr(windows, op)(axis=(2, 4, 6))
+  got = jax.jit(lambda a: pooling._pool_minmax(a, f, op))(jnp.asarray(x))
+  assert got.dtype == want.dtype
+  np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.int16, np.uint32, np.float32],
+                         ids=lambda d: np.dtype(d).name)
+def test_pool_striding_takes_each_windows_first_voxel(dtype):
+  """Partial edge windows keep their own first voxel: no padding."""
+  x = _with_extremes((2, 5, 7, 9), dtype, 3)
+  got = jax.jit(lambda a: pooling._pool_striding(a, (3, 2, 2)))(
+    jnp.asarray(x))
+  assert got.dtype == x.dtype
+  np.testing.assert_array_equal(np.asarray(got), x[:, ::2, ::2, ::3])
+
+
 def test_illegal_depth_is_refused():
   x = jnp.zeros((1, 8, 4, 4), jnp.uint8)
   with pytest.raises(ValueError, match="slab depth"):

@@ -101,6 +101,27 @@ def test_default_u64_task_batch_of_two_fits_one_chip(topo):
   assert total < 12e9, total
 
 
+@pytest.mark.parametrize("method,planes,shape,dtype,mips", [
+  ("average", 1, (2, 1, 64, 4096, 4096), jnp.uint8, 5),  # em_ds.jpeg
+  ("mode", 2, (2, 1, 64, 2048, 2048), jnp.uint32, 4),    # seg_ds.dense
+])
+def test_benchmark_pyramids_select_windows_without_gathers(
+    topo, method, planes, shape, dtype, mips):
+  """The benchmark cells' ChunkExecutor programs at --batch 2 on one chip
+  read their pooling windows without gathers (mode through reduce-windows
+  of one-voxel windows at the pooling strides, the uint8 average through
+  one reduce-window sum)."""
+  from igneous_tpu.parallel.executor import ChunkExecutor
+
+  mesh = Mesh(np.asarray(topo.devices[:1]), ("chunks",))
+  ex = ChunkExecutor(mesh, factors=((2, 2, 1),) * mips, method=method,
+                     planes=planes)
+  arr = _spec(shape, dtype, NamedSharding(mesh, P("chunks")))
+  hlo = _compile(ex._fn, (arr,) * planes).as_text()
+  gathers = hlo.count(" gather(")
+  assert gathers == 0, gathers
+
+
 def test_paged_pyramid_default_page_batch(one_chip):
   from igneous_tpu.parallel.paged import (
     _make_page_kernel, page_round_cap, page_shape,
