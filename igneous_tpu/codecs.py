@@ -12,6 +12,12 @@ in the byte stream and channel slowest — exactly the Precomputed "raw"
 spec. ``jpeg``/``png`` store one 2D image of width x and height y*z (the
 z slices stacked vertically), grayscale for 1 channel and RGB(A) for 3(4)
 — the Precomputed image-codec layout Neuroglancer decodes.
+
+Libraries: jpeg encodes through OpenCV (libjpeg-turbo), which lets go of
+the GIL while it encodes, so the encode pool's threads encode chunks side
+by side; its bytes equal PIL's at the same quality. PIL, whose jpeg
+encoder holds the GIL, encodes png and decodes both; its decoders let go
+of the GIL.
 """
 
 from __future__ import annotations
@@ -64,17 +70,23 @@ def _from_image_plane(plane: np.ndarray, shape) -> np.ndarray:
 
 
 def encode_jpeg(img: np.ndarray, quality: int = JPEG_DEFAULT_QUALITY) -> bytes:
-  from PIL import Image
+  """Encoded by OpenCV, which lets go of the GIL (module notes); the
+  bytes equal PIL's at the same quality (tests/test_image_codecs.py)."""
+  import cv2
 
   if img.dtype != np.uint8:
     raise ValueError(f"jpeg requires uint8 chunks, got {img.dtype}")
   if img.shape[3] not in (1, 3):
     raise ValueError(f"jpeg supports 1 or 3 channels, got {img.shape[3]}")
   plane = _to_image_plane(img)
-  pil = Image.fromarray(plane[..., 0] if plane.shape[2] == 1 else plane)
-  bio = io.BytesIO()
-  pil.save(bio, format="JPEG", quality=int(quality))
-  return bio.getvalue()
+  if plane.shape[2] == 3:
+    plane = np.ascontiguousarray(plane[..., ::-1])  # OpenCV takes BGR
+  ok, buf = cv2.imencode(
+    ".jpg", plane, [cv2.IMWRITE_JPEG_QUALITY, int(quality)]
+  )
+  if not ok:
+    raise ValueError(f"jpeg encode failed for a chunk of shape {img.shape}")
+  return buf.tobytes()
 
 
 def decode_jpeg(data: bytes, shape, dtype) -> np.ndarray:

@@ -93,6 +93,8 @@ class EncodePool:
   One pool per process (``shared_encode_pool``): thread churn is exactly
   the overhead the pipeline exists to remove, and deflate/puts from
   different tasks coexist safely because objects are independent.
+  Its threads run side by side only while the codec lets go of the GIL,
+  as zlib's deflate and OpenCV's jpeg encode do.
   """
 
   def __init__(self, threads: Optional[int] = None):
