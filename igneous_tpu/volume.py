@@ -51,6 +51,24 @@ def _renumber(img: np.ndarray, preserve_zero: bool = True):
   return renumber(img, start=1, preserve_zero=preserve_zero)
 
 
+def _place(out: np.ndarray, bbox: Bbox, chunk_bbx: Bbox,
+           chunk_img: np.ndarray) -> None:
+  """Copy the part of ``chunk_img`` (covering ``chunk_bbx``) that lies in
+  ``bbox`` into the cutout ``out`` of ``bbox``."""
+  isect = Bbox.intersection(chunk_bbx, bbox)
+  if isect.empty():
+    return
+  dst = tuple(
+    slice(int(a), int(b))
+    for a, b in zip(isect.minpt - bbox.minpt, isect.maxpt - bbox.minpt)
+  )
+  src = tuple(
+    slice(int(a), int(b))
+    for a, b in zip(isect.minpt - chunk_bbx.minpt, isect.maxpt - chunk_bbx.minpt)
+  )
+  out[dst] = chunk_img[src]
+
+
 class Volume:
   """A Precomputed volume rooted at ``cloudpath`` (file:// or mem://)."""
 
@@ -352,10 +370,18 @@ class Volume:
     else:
       inner = Bbox.intersection(bbox, bounds)
 
+    # Fortran order end to end: decoded chunks are F-order views, the
+    # device layout (c,z,y,x) is a zero-copy transpose of an F-order
+    # cutout, and raw encode is tobytes("F") — a C-order cutout here would
+    # force a full-volume transpose copy on BOTH sides of the compute.
+    out = self._cutout(bbox, inner)
     if self.meta.is_sharded(mip):
       from .sharded_image import download_sharded
 
       renders = download_sharded(self, inner, mip)
+      with _trace.span("volume.assemble"):
+        for chunk_bbx, chunk_img in renders:
+          _place(out, bbox, chunk_bbx, chunk_img)
     else:
       # stored chunks are grid-aligned and clamped to the volume bounds
       chunks = [
@@ -374,21 +400,12 @@ class Volume:
       keys = [self.meta.chunk_name(mip, c) for c in chunks]
       with _trace.span("volume.get", chunks=len(keys)):
         stored = self._parallel_get_stored(keys, parallel)
-      # read-only decode (possibly straight from the shared decode
-      # cache): the voxels are copied into the assembly buffer below, so
-      # a writable defensive copy here would be pure overhead
       workers = self._decode_workers(stored, mip, parallel)
-      with _trace.span("volume.decode", chunks=len(keys), workers=workers):
-        renders = list(zip(
-          chunks, self._decode_all(chunks, stored, mip, workers)
+      decode = _trace.span("volume.decode", chunks=len(keys), workers=workers)
+      with decode:
+        decode.set(placed=self._decode_into(
+          out, bbox, chunks, stored, mip, workers
         ))
-
-    # Fortran order end to end: decoded chunks are F-order views, the
-    # device layout (c,z,y,x) is a zero-copy transpose of an F-order
-    # cutout, and raw encode is tobytes("F") — C-order assembly here would
-    # force a full-volume transpose copy on BOTH sides of the compute.
-    with _trace.span("volume.assemble"):
-      out = self._assemble(bbox, inner, renders)
 
     if self.graphene is not None and (agglomerate or stop_layer is not None):
       from .graphene import voxel_chunk_index
@@ -419,30 +436,15 @@ class Volume:
       return out, mapping
     return out
 
-  def _assemble(self, bbox: Bbox, inner: Bbox, renders) -> np.ndarray:
+  def _cutout(self, bbox: Bbox, inner: Bbox) -> np.ndarray:
     out_shape = tuple(int(v) for v in bbox.size3()) + (self.num_channels,)
     if inner == bbox:
       # the chunk grid covers every output voxel (missing chunks arrive
       # background-filled): skip the background memset
-      out = np.empty(out_shape, dtype=self.dtype, order="F")
-    else:
-      out = np.full(
-        out_shape, self.background_color, dtype=self.dtype, order="F"
-      )
-    for chunk_bbx, chunk_img in renders:
-      isect = Bbox.intersection(chunk_bbx, bbox)
-      if isect.empty():
-        continue
-      dst = tuple(
-        slice(int(a), int(b))
-        for a, b in zip(isect.minpt - bbox.minpt, isect.maxpt - bbox.minpt)
-      )
-      src = tuple(
-        slice(int(a), int(b))
-        for a, b in zip(isect.minpt - chunk_bbx.minpt, isect.maxpt - chunk_bbx.minpt)
-      )
-      out[dst] = chunk_img[src]
-    return out
+      return np.empty(out_shape, dtype=self.dtype, order="F")
+    return np.full(
+      out_shape, self.background_color, dtype=self.dtype, order="F"
+    )
 
   def _parallel_get_stored(self, keys: List[str], parallel: Optional[int]):
     # stored-domain reads: (wire bytes, method) pairs, decompressed by
@@ -475,18 +477,45 @@ class Volume:
 
     return min(config.io_threads(), len(stored))
 
-  def _decode_all(self, chunks, stored, mip: int, workers: int):
-    """Decoded chunks in chunk order. PIL's decoders, zlib and the cseg
-    decoders release the GIL, so ``workers`` > 1 scales with the host's
-    cores. No caller downloads inside the IO pool, so its workers never
-    wait on the pool they run on."""
+  def _decode_into(self, out, bbox: Bbox, chunks, stored, mip: int,
+                   workers: int) -> int:
+    """Decode each chunk and copy its part of ``bbox`` into ``out`` in the
+    same step, so no decoded chunk outlives its copy. PIL's decoders, zlib,
+    the cseg decoders and numpy's copy release the GIL, so ``workers`` > 1
+    scales with the host's cores; chunks are disjoint, so the threads write
+    disjoint regions of ``out``. Returns how many chunks were placed side
+    by side: by the IO pool's workers and the calling thread, which takes
+    the chunks no worker has started yet rather than wait (0 inline). No
+    caller downloads inside the IO pool, so its workers never wait on the
+    pool they run on."""
+    def place(chunk_bbx, s):
+      _place(out, bbox, chunk_bbx, self._decode_stored(s, chunk_bbx, mip))
+
     if workers <= 1:
-      return [self._decode_stored(s, c, mip) for c, s in zip(chunks, stored)]
+      for chunk_bbx, s in zip(chunks, stored):
+        place(chunk_bbx, s)
+      return 0
     from .pipeline.encoder import shared_io_pool
 
-    return list(shared_io_pool().map(
-      lambda cs: self._decode_stored(cs[1], cs[0], mip), zip(chunks, stored)
-    ))
+    # x-neighbours write into the same pages of the F-order cutout: hand
+    # the pool z- and y-neighbours first, so its workers first-touch
+    # disjoint pages side by side
+    order = sorted(zip(chunks, stored), key=lambda cs: tuple(cs[0].minpt))
+    pool = shared_io_pool()
+    jobs = [(pool.submit(place, c, s), c, s) for c, s in order]
+    try:
+      # the calling thread places, from the back, what no worker has taken
+      for fut, c, s in reversed(jobs):
+        if fut.cancel():
+          place(c, s)
+      for fut, _c, _s in jobs:
+        if not fut.cancelled():
+          fut.result()
+    except BaseException:
+      for fut, _c, _s in jobs:
+        fut.cancel()
+      raise
+    return len(jobs)
 
   def __getitem__(self, slices) -> np.ndarray:
     bbox = self._interpret_slices(slices)

@@ -1,12 +1,16 @@
-"""A cutout's chunks decode side by side on the shared IO pool.
+"""A cutout's chunks decode and land in the cutout side by side on the
+shared IO pool.
 
 ``Volume.download`` fans the decode of a multi-chunk compressed cutout out
-to ``shared_io_pool()``; ``parallel=1``, single chunks and uncompressed
-raw chunks stay serial. These tests hold the pooled decode to the serial
-one voxel for voxel, and check that corruption, the decoded-chunk cache,
-``fill_missing`` and the ``workers`` stat of the ``volume.decode`` span
-behave as on the serial path, and that downloads run from the prefetch
-pool finish with a narrow IO pool.
+to ``shared_io_pool()``, and each worker copies the chunk it decoded into
+its place in the cutout; ``parallel=1``, single chunks and uncompressed
+raw chunks stay serial. These tests hold the pooled decode-and-place to
+the serial one byte for byte, F-order, for boxes inside and past the
+bounds and for several channels; and check that corruption, the
+decoded-chunk cache (never aliased into a cutout), ``fill_missing`` and
+the ``workers`` and ``placed`` stats of the ``volume.decode`` span behave
+as on the serial path, and that downloads run from the prefetch pool
+finish with a narrow IO pool.
 """
 
 import concurrent.futures as cf
@@ -59,18 +63,21 @@ def _image(rng, dtype):
   return img + np.uint64(2**40)
 
 
-# (encoding, wire compression, dtype)
+# (encoding, wire compression, dtype, channels)
 CODECS = {
-  "jpeg_u8": ("jpeg", None, np.uint8),
-  "gzip_raw_u8": ("raw", "gzip", np.uint8),
-  "gzip_raw_u64": ("raw", "gzip", np.uint64),
-  "cseg_u64": ("compressed_segmentation", None, np.uint64),
+  "jpeg_u8": ("jpeg", None, np.uint8, 1),
+  "gzip_raw_u8": ("raw", "gzip", np.uint8, 1),
+  "gzip_raw_u8x3": ("raw", "gzip", np.uint8, 3),
+  "gzip_raw_u64": ("raw", "gzip", np.uint64, 1),
+  "cseg_u64": ("compressed_segmentation", None, np.uint64, 1),
 }
 
 
 def _volume(tmp_path, rng, name):
-  encoding, compress, dtype = CODECS[name]
+  encoding, compress, dtype, channels = CODECS[name]
   data = _image(rng, dtype)
+  if channels > 1:
+    data = np.stack([data, ~data, data // 3], axis=-1)
   path = f"file://{tmp_path}/{name}"
   Volume.from_numpy(data, path, chunk_size=CHUNK, encoding=encoding,
                     compress=compress)
@@ -87,14 +94,27 @@ def _decode_spans(fn):
   return out, spans
 
 
+def _slices(box, origin):
+  return tuple(slice(int(a), int(b)) for a, b in zip(
+    np.array(box.minpt) - np.array(origin),
+    np.array(box.maxpt) - np.array(origin)))
+
+
 @pytest.mark.parametrize("name", sorted(CODECS))
-@pytest.mark.parametrize("region", ["whole", "edge", "missing"])
+@pytest.mark.parametrize(
+  "region", ["whole", "edge", "missing", "unbounded", "cached"])
 def test_pooled_decode_equals_serial(tmp_path, rng, name, region):
   path, data = _volume(tmp_path, rng, name)
-  vol = Volume(path, fill_missing=(region == "missing"))
+  unbounded = region == "unbounded"
+  vol = Volume(path, fill_missing=(region == "missing"),
+               bounded=not unbounded, background_color=7 if unbounded else 0)
   bounds = vol.meta.bounds(0)
-  if region == "whole":
+  if region in ("whole", "cached"):
     bbox = bounds
+  elif unbounded:
+    # past the bounds on both sides of x and z: background outside, the
+    # layer's chunks inside
+    bbox = Bbox((-5, 10, -3), tuple(int(v) + 6 for v in bounds.maxpt))
   else:
     # off the chunk grid, reaching into the clamped edge chunks
     bbox = Bbox((5, 3, 2), tuple(int(v) for v in bounds.maxpt))
@@ -104,24 +124,42 @@ def test_pooled_decode_equals_serial(tmp_path, rng, name, region):
     vol.cf.delete([key])
 
   chunk_cache.clear()
+  if region == "cached":
+    # both reads below are served from the chunk cache this one fills
+    vol.download(bbox, parallel=1)
   serial, serial_spans = _decode_spans(lambda: vol.download(bbox, parallel=1))
-  chunk_cache.clear()
+  if region != "cached":
+    chunk_cache.clear()
   pooled, pooled_spans = _decode_spans(lambda: vol.download(bbox))
 
   assert pooled.dtype == serial.dtype and pooled.shape == serial.shape
   assert pooled.tobytes("A") == serial.tobytes("A")
-  assert [s["workers"] for s in serial_spans] == [1]
+  assert serial.flags.f_contiguous and pooled.flags.f_contiguous
+  assert [(s["workers"], s["placed"]) for s in serial_spans] == [(1, 0)]
   (span,) = pooled_spans
   assert span["chunks"] == 12 and span["workers"] > 1
+  assert span["placed"] == 12
+  inner = Bbox.intersection(bbox, bounds)
   if region == "missing":
-    lo = np.array(chunk.minpt) - np.array(bbox.minpt)
-    hi = np.array(chunk.maxpt) - np.array(bbox.minpt)
-    hole = pooled[lo[0]:hi[0], lo[1]:hi[1], lo[2]:hi[2]]
-    assert (hole == 0).all()
+    assert (pooled[_slices(chunk, bbox.minpt)] == 0).all()
   elif CODECS[name][0] != "jpeg":
     # lossless codecs: the cutout is the source itself
-    sl = tuple(slice(int(a), int(b)) for a, b in zip(bbox.minpt, bbox.maxpt))
-    assert np.array_equal(pooled[..., 0], data[sl])
+    want = data[_slices(inner, (0, 0, 0))]
+    assert np.array_equal(pooled[_slices(inner, bbox.minpt)],
+                          want.reshape(want.shape[:3] + (-1,)))
+  if unbounded:
+    outside = np.ones(pooled.shape[:3], dtype=bool)
+    outside[_slices(inner, bbox.minpt)] = False
+    assert outside.any() and (pooled[outside] == 7).all()
+  if region == "cached":
+    assert telemetry.counters_snapshot()["chunk_cache.hits"] == 24
+    # no cutout aliases a cached chunk: writing into the ones served from
+    # the cache leaves the next read of the box as it was
+    want = serial.tobytes("A")
+    serial[...] = 3
+    pooled[...] = 3
+    assert vol.download(bbox, parallel=1).tobytes("A") == want
+    assert vol.download(bbox).tobytes("A") == want
 
 
 def test_corrupt_chunk_among_many_raises_and_quarantines_its_key(
@@ -194,10 +232,13 @@ def test_workers_stat(tmp_path, rng, case, workers):
   out, (span,) = _decode_spans(lambda: vol.download(bbox, parallel=parallel))
   sl = tuple(slice(int(a), int(b)) for a, b in zip(bbox.minpt, bbox.maxpt))
   assert np.array_equal(out[..., 0], data[sl])
+  assert out.flags.f_contiguous
   if workers == "many":
     assert 1 < span["workers"] <= span["chunks"]
+    # every chunk lands in the cutout on the pool worker that decoded it
+    assert span["placed"] == span["chunks"]
   else:
-    assert span["workers"] == workers
+    assert span["workers"] == workers and span["placed"] == 0
 
 
 def test_prefetch_pool_downloads_finish_on_a_narrow_io_pool(
